@@ -120,7 +120,7 @@ FIELD_DOCS = {
         "why the device-mode kernel lane did not run (absent when it did)",
     "device_backend": "jax backend the device lane ran on (tpu/gpu)",
     "device_pack_row_tile_gb_s":
-        "row_tile -> GB/s sweep of blob_pack_fused_pallas, compiled "
+        "row_tile -> GB/s sweep of blob_pack_pallas, compiled "
         "(interpret=False), block_until_ready timing",
     "device_best_row_tile": "argmax of device_pack_row_tile_gb_s",
     "device_pack_gb_s": "GB/s of the best row_tile config",
@@ -312,7 +312,7 @@ def bench_pack_device(quick: bool = False) -> Tuple[List[Row], dict]:
 
     from repro.kernels.blob_codec.kernel import compress_pack_fused_pallas
     from repro.kernels.blob_pack.kernel import (SWEEP_ROW_TILES,
-                                                blob_pack_fused_pallas)
+                                                blob_pack_pallas)
     from repro.shuffle.binning import sorted_order
 
     T, d, bins, cap, x, keys = _pack_inputs(quick)
@@ -330,7 +330,7 @@ def bench_pack_device(quick: bool = False) -> Tuple[List[Row], dict]:
 
     sweep = {}
     for rt in SWEEP_ROW_TILES:
-        per = timed(lambda: blob_pack_fused_pallas(
+        per = timed(lambda: blob_pack_pallas(
             x, order, starts, counts, capacity=cap, interpret=False,
             row_tile=rt))
         sweep[str(rt)] = logical / per / 1e9

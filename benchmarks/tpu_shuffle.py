@@ -1,9 +1,10 @@
 """TPU-adaptation benchmark: flat vs blob-hierarchical MoE dispatch.
 
-Spawns an 8-device subprocess (2 pods × 2 data × 2 model) and reports,
-per mode: wall time per step, inter-pod (DCN) payload bytes, and HLO
-collective statistics from the compiled module — the roofline-level
-evidence for the BlobShuffle adaptation (see EXPERIMENTS.md §Perf).
+Spawns a subprocess with 8 forced CPU devices (2 pods × 2 data × 2
+model) and reports, per mode: wall time per step on those CPU devices,
+inter-pod (DCN) payload bytes, and HLO collective statistics from the
+compiled module. The child runs on the CPU whatever the parent's
+platform: a parent that holds a chip would leave the child none.
 """
 
 from __future__ import annotations
@@ -63,11 +64,13 @@ print('RESULT ' + json.dumps(out))
 
 
 def run() -> List[Row]:
-    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
+    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"),
+               JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(_BODY)],
                        env=env, capture_output=True, text=True, timeout=900)
     if r.returncode != 0:
-        return [("tpu_shuffle.error", 0, r.stderr.splitlines()[-1][:120])]
+        raise RuntimeError(f"tpu_shuffle child failed (rc {r.returncode}):"
+                           f"\n{r.stderr}")
     line = [l for l in r.stdout.splitlines() if l.startswith("RESULT ")][0]
     data = json.loads(line[len("RESULT "):])
     rows: List[Row] = []

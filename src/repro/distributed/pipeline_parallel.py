@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import jaxcompat
-
 
 def gpipe_apply(stage_fn: Callable, params, x, *, mesh, n_micro: int,
                 stage_axis: str = "pod"):
@@ -81,7 +79,7 @@ def gpipe_apply(stage_fn: Callable, params, x, *, mesh, n_micro: int,
         return out
 
     spec_p = jax.tree.map(lambda _: P(stage_axis), params)
-    out = jaxcompat.shard_map(
+    out = jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec_p, P()),
         out_specs=P(),

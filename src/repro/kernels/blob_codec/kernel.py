@@ -1,19 +1,23 @@
-"""Pallas TPU kernels for the fused blob compress+pack codec.
+"""Pallas TPU kernels for the blob compress+pack codec.
 
-``compress_pack_fused_pallas`` extends ``blob_pack_fused_pallas``'s tiled
-vector gather with an in-register quantize: each program instance gathers
-FUSED_ROW_TILE destination rows with one ``jnp.take``, masks them, then
-computes the per-row absmax scale and int8 codes before anything is
-stored — the uncompressed f32 blob layout never materializes in HBM. Two
-outputs per tile: the int8 codes block and the f32 scales block.
+``compress_pack_fused_pallas`` gathers each tile of ``row_tile`` blob
+rows into VMEM, one DMA per row (``repro.kernels.row_dma``), then
+computes the per-row absmax scale and the int8 codes before anything is
+stored: the uncompressed blob layout never reaches HBM. Rows are
+gathered as float32, the dtype the quantizer works in. Two outputs per
+tile: the int8 codes and a lane-dense (1, row_tile) block of scales.
 
-``unpack_decompress_fused_pallas`` is the inverse on the Debatcher side:
-one gather pulls the tile's int8 rows *and* their scales, and the
-dequantized f32 rows are produced in the same pass.
+``unpack_decompress_fused_pallas`` is the inverse on the Debatcher
+side. Its gather is the ``blob_unpack`` kernel run on the int8 codes.
+The dequantize then runs as one elementwise pass in the same jitted
+program: int8 rows travel as packed (4, d / 4) slabs
+(``repro.kernels.row_dma``), and spreading a slab back out to one code
+per lane inside the kernel would need a relayout the kernel cannot
+express.
 
-Both are bit-exact (in interpret mode) with the composed oracles in
-``ref.py``: the quantizer is the *same function* (``ref.quantize_rows``)
-applied to the gathered tile, so kernel and oracle cannot drift.
+Both are bit-exact with the composed oracles in ``ref.py``: the
+quantizer is the *same function* (``ref.quantize_rows``) applied to the
+gathered tile, so kernel and oracle cannot drift.
 """
 
 from __future__ import annotations
@@ -24,31 +28,37 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.blob_codec.ref import quantize_rows
+from repro.kernels.blob_pack.kernel import bin_rows
+from repro.kernels.blob_unpack.kernel import blob_unpack_pallas
+from repro.kernels.row_dma import (LANES, as_slabs, start_row_copy,
+                                   wait_row_copies)
 
 FUSED_ROW_TILE = 128
 
 
 def _make_compress_pack_kernel(capacity: int, row_tile: int):
-    def kernel(order_ref, starts_ref, counts_ref, x_ref, q_ref, scale_ref):
+    def kernel(order_ref, starts_ref, counts_ref, x_hbm, q_ref, scale_ref,
+               rows_buf, sem):
         b = pl.program_id(0)
         t = pl.program_id(1)
-        start = starts_ref[b]
-        count = jnp.minimum(counts_ref[b], capacity)
-        order = order_ref[...]
-        U = order.shape[0]
-        r = (t * row_tile + jax.lax.broadcasted_iota(
-            jnp.int32, (row_tile, 1), 0)[:, 0])
-        pos = jnp.clip(start + r, 0, U - 1)
-        toks = jnp.take(order, pos, axis=0)
-        rows = jnp.take(x_ref[...], toks, axis=0)   # tiled vector gather
-        keep = (r < count)[:, None]
-        rows = jnp.where(keep, rows, jnp.zeros_like(rows))
-        # in-register symmetric per-row int8 quantize; padding -> (0, 1.0)
+        n = bin_rows(counts_ref, b, t, capacity=capacity, row_tile=row_tile)
+        first = starts_ref[b] + t * row_tile
+
+        def start(i, carry):
+            start_row_copy(x_hbm, order_ref[first + i], rows_buf, i, sem)
+            return carry
+
+        jax.lax.fori_loop(0, n, start, 0)
+        wait_row_copies(x_hbm, rows_buf, sem, n)
+        keep = jax.lax.broadcasted_iota(jnp.int32, (row_tile, 1), 0) < n
+        rows = jnp.where(keep, rows_buf[:, 0, :], 0.0)
+        # symmetric per-row int8 quantize; padding -> (0, 1.0)
         q, scale = quantize_rows(rows)
-        q_ref[0, :, :] = q
-        scale_ref[0, :] = scale
+        q_ref[0] = q[:, :q_ref.shape[-1]]        # drop the lane padding
+        scale_ref[0] = scale.reshape(1, row_tile)
     return kernel
 
 
@@ -60,71 +70,43 @@ def compress_pack_fused_pallas(x, order, starts, counts, *, capacity: int,
     """Single-pass gather+quantize pack (bit-exact with
     ``compress_pack_ref``): (T, d) tokens -> (q int8 (bins, capacity, d),
     scales f32 (bins, capacity)). ``row_tile`` overrides the tile depth
-    (the device benchmark lane sweeps it)."""
+    (the device benchmark lane sweeps it); it is rounded up to whole
+    128-lane rows of scales, or to the whole capacity."""
     bins = starts.shape[0]
     d = x.shape[-1]
-    row_tile = min(row_tile or FUSED_ROW_TILE, capacity)
-    grid = (bins, -(-capacity // row_tile))
-    return pl.pallas_call(
+    slabs = as_slabs(x.astype(jnp.float32))
+    row_tile = min(-(-(row_tile or FUSED_ROW_TILE) // LANES) * LANES,
+                   capacity)
+    q, scales = pl.pallas_call(
         _make_compress_pack_kernel(capacity, row_tile),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(order.shape, lambda b, t: (0,)),      # full order
-            pl.BlockSpec(starts.shape, lambda b, t: (0,)),
-            pl.BlockSpec(counts.shape, lambda b, t: (0,)),
-            pl.BlockSpec(x.shape, lambda b, t: (0, 0)),        # tokens
-        ],
-        out_specs=[
-            pl.BlockSpec((1, row_tile, d), lambda b, t: (b, t, 0)),
-            pl.BlockSpec((1, row_tile), lambda b, t: (b, t)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(bins, pl.cdiv(capacity, row_tile)),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[
+                pl.BlockSpec((1, row_tile, d), lambda b, t, *_: (b, t, 0)),
+                pl.BlockSpec((1, 1, row_tile), lambda b, t, *_: (b, 0, t)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((row_tile,) + slabs.shape[1:], jnp.float32),
+                pltpu.SemaphoreType.DMA(())]),
         out_shape=[
             jax.ShapeDtypeStruct((bins, capacity, d), jnp.int8),
-            jax.ShapeDtypeStruct((bins, capacity), jnp.float32),
+            jax.ShapeDtypeStruct((bins, 1, capacity), jnp.float32),
         ],
         interpret=interpret,
-    )(order, starts, counts, x)
+    )(order.astype(jnp.int32), starts.astype(jnp.int32),
+      counts.astype(jnp.int32), slabs)
+    return q, scales.reshape(bins, capacity)
 
 
-def _make_unpack_decompress_kernel(U: int, row_tile: int):
-    def kernel(slot_ref, valid_ref, q_ref, scale_ref, out_ref):
-        t = pl.program_id(0)
-        flat_q = q_ref[...]
-        R = flat_q.shape[0]
-        u = (t * row_tile + jax.lax.broadcasted_iota(
-            jnp.int32, (row_tile, 1), 0)[:, 0])
-        uc = jnp.minimum(u, U - 1)
-        s = jnp.clip(jnp.take(slot_ref[...], uc, axis=0), 0, R - 1)
-        q = jnp.take(flat_q, s, axis=0)             # tiled vector gather
-        scale = jnp.take(scale_ref[...], s, axis=0)
-        rows = q.astype(jnp.float32) * scale[:, None]   # dequantize
-        keep = ((u < U) & jnp.take(valid_ref[...], uc, axis=0))[:, None]
-        out_ref[:, :] = jnp.where(keep, rows, jnp.zeros_like(rows))
-    return kernel
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "row_tile"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def unpack_decompress_fused_pallas(q, scales, slot, valid, *,
-                                   interpret: bool = True,
-                                   row_tile: Optional[int] = None):
-    """Single-pass gather+dequantize unpack (bit-exact with
-    ``unpack_decompress_ref``): compressed blob layout -> (U, d) f32."""
-    bins, cap, d = q.shape
-    U = slot.shape[0]
-    flat_q = q.reshape(bins * cap, d)
-    flat_s = scales.reshape(bins * cap)
-    row_tile = min(row_tile or FUSED_ROW_TILE, U)
-    grid = (-(-U // row_tile),)
-    return pl.pallas_call(
-        _make_unpack_decompress_kernel(U, row_tile),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(slot.shape, lambda t: (0,)),
-            pl.BlockSpec(valid.shape, lambda t: (0,)),
-            pl.BlockSpec(flat_q.shape, lambda t: (0, 0)),
-            pl.BlockSpec(flat_s.shape, lambda t: (0,)),
-        ],
-        out_specs=pl.BlockSpec((row_tile, d), lambda t: (t, 0)),
-        out_shape=jax.ShapeDtypeStruct((U, d), jnp.float32),
-        interpret=interpret,
-    )(slot, valid, flat_q, flat_s)
+                                   interpret: bool = True):
+    """Gather+dequantize unpack (bit-exact with ``unpack_decompress_ref``):
+    compressed blob layout -> (U, d) f32."""
+    rows = blob_unpack_pallas(q, slot, valid, interpret=interpret)
+    flat_s = scales.reshape(-1)
+    scale = flat_s[jnp.clip(slot, 0, flat_s.shape[0] - 1)]
+    return jnp.where(valid[:, None],
+                     rows.astype(jnp.float32) * scale[:, None], 0.0)

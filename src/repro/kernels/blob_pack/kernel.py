@@ -1,21 +1,16 @@
-"""Pallas TPU kernels for blob_pack (Batcher gather into blob layout).
+"""Pallas TPU kernel for blob_pack (the Batcher's gather into blob layout).
 
-Both kernels now share one whole-tile body: each program instance
-computes the tile's destination rows at once (iota → clip → order
-lookup) and gathers all ``row_tile`` rows with a single vectorized
-``jnp.take`` — the original per-row ``fori_loop`` body (which serialized
-one gather per destination row) is gone.
+Grid (bins, capacity / row_tile). Program (b, t) copies rows
+``t * row_tile ...`` of bin ``b`` straight from the token rows in HBM to
+the blob in HBM, one DMA per row (``repro.kernels.row_dma``): nothing is
+staged in VMEM, so the token array may be any size. ``order``,
+``starts`` and ``counts`` are prefetched into SMEM, where the kernel
+reads them as scalars. The output is aliased to a zero buffer, and rows
+past a bin's count are never written, so they stay zero.
 
-Tile geometry is retuned for the VPU: ``ROW_TILE`` was 8 — far below
-the (sublane × lane) shapes the vector unit wants — and is now 128, so
-a tile is a (128, d) block: lane-aligned along the whole feature dim and
-deep enough in the sublane dim to amortize the gather's index math. Both
-wrappers take a ``row_tile`` override so the device-mode benchmark lane
-(``benchmarks/micro.py``) can sweep row-tile configurations the way
-MaxText tunes its combine thresholds, without editing kernel source.
-
-The feature dim is kept whole per row (d ≤ a few K → tile × d blocks sit
-comfortably in VMEM and are lane-aligned for the VPU).
+``row_tile`` sets how many row DMAs one program issues before it waits
+for them; ``benchmarks/micro.py`` sweeps it on the device. It changes no
+output bit.
 """
 
 from __future__ import annotations
@@ -26,56 +21,42 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.row_dma import (as_slabs, from_slabs, start_row_copy,
+                                   wait_row_copies)
 
 ROW_TILE = 128
-FUSED_ROW_TILE = 128
 
 #: row-tile candidates the device benchmark lane sweeps (clamped to
-#: capacity at call time); 8 is kept as the degenerate legacy point so
-#: the sweep shows what the retune bought
+#: capacity at call time)
 SWEEP_ROW_TILES = (8, 32, 64, 128, 256)
 
 
-def _make_tile_kernel(capacity: int, row_tile: int):
-    """Whole-tile gather body shared by the plain and fused pack kernels:
-    one vectorized ``jnp.take`` per (bin, tile) program instance."""
-    def kernel(order_ref, starts_ref, counts_ref, x_ref, out_ref):
+def bin_rows(counts_ref, b, t, *, capacity: int, row_tile: int):
+    """Rows of tile ``t`` of bin ``b`` that hold a record (a prefix)."""
+    filled = jnp.minimum(counts_ref[b], capacity)
+    return jnp.clip(filled - t * row_tile, 0, row_tile)
+
+
+def _make_pack_kernel(capacity: int, row_tile: int):
+    def kernel(order_ref, starts_ref, counts_ref, x_hbm, zeros_hbm,
+               out_hbm, sem):
+        del zeros_hbm                      # aliased with out_hbm
         b = pl.program_id(0)
         t = pl.program_id(1)
-        start = starts_ref[b]
-        count = jnp.minimum(counts_ref[b], capacity)
-        order = order_ref[...]
-        U = order.shape[0]
-        # whole tile of destination rows at once (no fori_loop):
-        r = (t * row_tile + jax.lax.broadcasted_iota(
-            jnp.int32, (row_tile, 1), 0)[:, 0])
-        pos = jnp.clip(start + r, 0, U - 1)
-        toks = jnp.take(order, pos, axis=0)
-        rows = jnp.take(x_ref[...], toks, axis=0)   # tiled vector gather
-        keep = (r < count)[:, None]
-        out_ref[0, :, :] = jnp.where(keep, rows, jnp.zeros_like(rows))
+        n = bin_rows(counts_ref, b, t, capacity=capacity, row_tile=row_tile)
+        first = starts_ref[b] + t * row_tile
+        out_bin = out_hbm.at[b]
+
+        def start(i, carry):
+            start_row_copy(x_hbm, order_ref[first + i], out_bin,
+                           t * row_tile + i, sem)
+            return carry
+
+        jax.lax.fori_loop(0, n, start, 0)
+        wait_row_copies(x_hbm, out_bin, sem, n)
     return kernel
-
-
-def _pack_call(x, order, starts, counts, *, capacity: int, row_tile: int,
-               interpret: bool):
-    bins = starts.shape[0]
-    d = x.shape[-1]
-    row_tile = min(row_tile, capacity)
-    grid = (bins, -(-capacity // row_tile))
-    return pl.pallas_call(
-        _make_tile_kernel(capacity, row_tile),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(order.shape, lambda b, t: (0,)),      # full order
-            pl.BlockSpec(starts.shape, lambda b, t: (0,)),
-            pl.BlockSpec(counts.shape, lambda b, t: (0,)),
-            pl.BlockSpec(x.shape, lambda b, t: (0, 0)),        # tokens
-        ],
-        out_specs=pl.BlockSpec((1, row_tile, d), lambda b, t: (b, t, 0)),
-        out_shape=jax.ShapeDtypeStruct((bins, capacity, d), x.dtype),
-        interpret=interpret,
-    )(order, starts, counts, x)
 
 
 @functools.partial(jax.jit,
@@ -83,20 +64,25 @@ def _pack_call(x, order, starts, counts, *, capacity: int, row_tile: int,
 def blob_pack_pallas(x, order, starts, counts, *, capacity: int,
                      interpret: bool = True,
                      row_tile: Optional[int] = None):
-    """Two-pass-compatible pack kernel (same contract as ``blob_pack_ref``),
-    now running the whole-tile gather body — the ``fori_loop`` generation
-    is retired."""
-    return _pack_call(x, order, starts, counts, capacity=capacity,
-                      row_tile=row_tile or ROW_TILE, interpret=interpret)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("capacity", "interpret", "row_tile"))
-def blob_pack_fused_pallas(x, order, starts, counts, *, capacity: int,
-                           interpret: bool = True,
-                           row_tile: Optional[int] = None):
-    """Single-pass tiled-vector-gather pack (same contract and bit-exact
-    output as ``blob_pack_pallas`` / ``blob_pack_ref``)."""
-    return _pack_call(x, order, starts, counts, capacity=capacity,
-                      row_tile=row_tile or FUSED_ROW_TILE,
-                      interpret=interpret)
+    """(T, d) tokens + sorted-order description -> (bins, capacity, d),
+    bit-exact with ``blob_pack_ref``."""
+    bins = starts.shape[0]
+    d = x.shape[-1]
+    slabs = as_slabs(x)
+    row_tile = min(row_tile or ROW_TILE, capacity)
+    out_shape = (bins, capacity) + slabs.shape[1:]
+    out = pl.pallas_call(
+        _make_pack_kernel(capacity, row_tile),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(bins, pl.cdiv(capacity, row_tile)),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.SemaphoreType.DMA(())]),
+        out_shape=jax.ShapeDtypeStruct(out_shape, x.dtype),
+        input_output_aliases={4: 0},
+        interpret=interpret,
+    )(order.astype(jnp.int32), starts.astype(jnp.int32),
+      counts.astype(jnp.int32), slabs, jnp.zeros(out_shape, x.dtype))
+    return from_slabs(out, d)

@@ -2,9 +2,8 @@
 
 Also provides ``pack_from_keys`` which computes the sorted-order inputs
 (argsort by destination) the way the shuffle layer does, and
-``blob_pack_fused`` — the single-pass path that fuses the sort/rank front
-half of ``bin_pack`` with the tiled-vector-gather kernel, replacing the
-two-pass rank/scatter + row-loop gather structure.
+``blob_pack_fused`` — the single-pass path that runs the sort/rank front
+half of ``bin_pack`` and the pack kernel in one jitted program.
 """
 
 from __future__ import annotations
@@ -15,8 +14,7 @@ import jax
 
 from repro.kernels.blob_pack.host import (blob_pack_fused_host,
                                           sorted_order_np)
-from repro.kernels.blob_pack.kernel import (blob_pack_fused_pallas,
-                                            blob_pack_pallas)
+from repro.kernels.blob_pack.kernel import blob_pack_pallas
 from repro.kernels.blob_pack.ref import blob_pack_ref
 from repro.shuffle.binning import sorted_order
 
@@ -56,8 +54,7 @@ def pack_from_keys(x, keys, *, num_bins: int, capacity: int,
 def blob_pack_fused(x, keys, *, num_bins: int, capacity: int,
                     use_pallas: bool = None):
     """Fused single-pass pack: ``bin_pack``'s sort/rank and the gather run
-    in one jitted pass, and the Pallas kernel gathers whole tiles with
-    vectorized ``jnp.take`` instead of a row-at-a-time ``fori_loop``.
+    in one jitted pass.
 
     (tokens, destination keys) -> ((bins, capacity, d), sorted-order
     description). Bit-exact with ``pack_from_keys``."""
@@ -65,9 +62,8 @@ def blob_pack_fused(x, keys, *, num_bins: int, capacity: int,
     if use_pallas is None:
         use_pallas = _on_tpu()
     if use_pallas:
-        out = blob_pack_fused_pallas(x, order, starts, counts,
-                                     capacity=capacity,
-                                     interpret=not _on_tpu())
+        out = blob_pack_pallas(x, order, starts, counts, capacity=capacity,
+                               interpret=not _on_tpu())
     else:
         out = blob_pack_ref(x, order, starts, counts, capacity=capacity)
     return out, (order, starts, counts)

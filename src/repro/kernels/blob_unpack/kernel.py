@@ -1,12 +1,11 @@
-"""Pallas TPU kernels for blob_unpack (Debatcher extract).
+"""Pallas TPU kernel for blob_unpack (the Debatcher's extract).
 
-``blob_unpack_pallas`` — reference kernel: grid (ceil(U / ROW_TILE),),
-each instance gathers ROW_TILE unit rows one at a time via ``fori_loop``.
-
-``blob_unpack_fused_pallas`` — fused tile kernel matching the fused pack:
-the whole tile's slot indices load at once and all FUSED_ROW_TILE rows
-come out of a single vectorized ``jnp.take`` gather, masked and stored
-with no per-row loop.
+Grid (U / row_tile,). Program ``t`` copies the rows of units
+``t * row_tile ...`` straight from the blob in HBM to the output in HBM,
+one DMA per valid unit (``repro.kernels.row_dma``). Each unit's source
+row, or -1 for an invalid (capacity-dropped) unit, is prefetched into
+SMEM. The output is aliased to a zero buffer, and rows of invalid units
+are never written, so they stay zero.
 """
 
 from __future__ import annotations
@@ -16,82 +15,57 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-ROW_TILE = 8
-FUSED_ROW_TILE = 128
+from repro.kernels.row_dma import (as_slabs, from_slabs, start_row_copy,
+                                   wait_row_copies)
+
+ROW_TILE = 128
 
 
-def _make_kernel(U: int, row_tile: int):
-    def kernel(slot_ref, valid_ref, buf_ref, out_ref):
-        t = pl.program_id(0)
-        R = buf_ref.shape[0]
+def _make_kernel(row_tile: int):
+    def kernel(src_ref, buf_hbm, zeros_hbm, out_hbm, sem):
+        del zeros_hbm                      # aliased with out_hbm
+        base = pl.program_id(0) * row_tile
 
-        def body(i, _):
-            u = t * row_tile + i
-            uc = jnp.minimum(u, U - 1)
-            s = jnp.clip(slot_ref[uc], 0, R - 1)
-            row = buf_ref[s, :]
-            keep = (u < U) & valid_ref[uc]
-            out_ref[i, :] = jnp.where(keep, row, jnp.zeros_like(row))
-            return 0
+        def start(i, n):
+            s = src_ref[base + i]
+            keep = s >= 0
 
-        jax.lax.fori_loop(0, row_tile, body, 0)
+            @pl.when(keep)
+            def _():
+                start_row_copy(buf_hbm, s, out_hbm, base + i, sem)
+
+            return n + keep.astype(jnp.int32)
+
+        n = jax.lax.fori_loop(0, row_tile, start, jnp.int32(0))
+        wait_row_copies(buf_hbm, out_hbm, sem, n)
     return kernel
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def blob_unpack_pallas(buf, slot, valid, *, interpret: bool = True):
+    """(bins, cap, d) blob + (U,) slots and valid mask -> (U, d),
+    bit-exact with ``blob_unpack_ref``."""
     bins, cap, d = buf.shape
     U = slot.shape[0]
-    flat = buf.reshape(bins * cap, d)
+    slabs = as_slabs(buf.reshape(bins * cap, d))
     row_tile = min(ROW_TILE, U)
-    grid = (-(-U // row_tile),)
-    return pl.pallas_call(
-        _make_kernel(U, row_tile),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(slot.shape, lambda t: (0,)),
-            pl.BlockSpec(valid.shape, lambda t: (0,)),
-            pl.BlockSpec(flat.shape, lambda t: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((row_tile, d), lambda t: (t, 0)),
-        out_shape=jax.ShapeDtypeStruct((U, d), buf.dtype),
+    pad = -U % row_tile
+    src = jnp.where(valid, jnp.clip(slot, 0, bins * cap - 1), -1)
+    src = jnp.pad(src.astype(jnp.int32), (0, pad), constant_values=-1)
+    out_shape = (U,) + slabs.shape[1:]
+    out = pl.pallas_call(
+        _make_kernel(row_tile),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=((U + pad) // row_tile,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.SemaphoreType.DMA(())]),
+        out_shape=jax.ShapeDtypeStruct(out_shape, buf.dtype),
+        input_output_aliases={2: 0},
         interpret=interpret,
-    )(slot, valid, flat)
-
-
-def _make_fused_kernel(U: int, row_tile: int):
-    def kernel(slot_ref, valid_ref, buf_ref, out_ref):
-        t = pl.program_id(0)
-        flat = buf_ref[...]
-        R = flat.shape[0]
-        u = (t * row_tile + jax.lax.broadcasted_iota(
-            jnp.int32, (row_tile, 1), 0)[:, 0])
-        uc = jnp.minimum(u, U - 1)
-        s = jnp.clip(jnp.take(slot_ref[...], uc, axis=0), 0, R - 1)
-        rows = jnp.take(flat, s, axis=0)            # tiled vector gather
-        keep = ((u < U) & jnp.take(valid_ref[...], uc, axis=0))[:, None]
-        out_ref[:, :] = jnp.where(keep, rows, jnp.zeros_like(rows))
-    return kernel
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def blob_unpack_fused_pallas(buf, slot, valid, *, interpret: bool = True):
-    """Tiled-vector-gather unpack (bit-exact with ``blob_unpack_ref``)."""
-    bins, cap, d = buf.shape
-    U = slot.shape[0]
-    flat = buf.reshape(bins * cap, d)
-    row_tile = min(FUSED_ROW_TILE, U)
-    grid = (-(-U // row_tile),)
-    return pl.pallas_call(
-        _make_fused_kernel(U, row_tile),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(slot.shape, lambda t: (0,)),
-            pl.BlockSpec(valid.shape, lambda t: (0,)),
-            pl.BlockSpec(flat.shape, lambda t: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((row_tile, d), lambda t: (t, 0)),
-        out_shape=jax.ShapeDtypeStruct((U, d), buf.dtype),
-        interpret=interpret,
-    )(slot, valid, flat)
+    )(src, slabs, jnp.zeros(out_shape, buf.dtype))
+    return from_slabs(out, d)

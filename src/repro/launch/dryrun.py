@@ -1,10 +1,12 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 # Multi-pod dry-run: lower + compile every (arch × shape) cell on the
 # production meshes and extract roofline terms from the compiled artifact.
-# The two lines above MUST run before any jax import (device count locks on
-# first init); tests/benches never import this module.
+# A CPU tool by design: its 512 devices are forced host devices. The lines
+# above MUST run before any jax import (device count locks on first init);
+# tests/benches never import this module.
 
 import argparse      # noqa: E402
 import dataclasses   # noqa: E402
@@ -17,7 +19,6 @@ import jax           # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
-from repro import jaxcompat  # noqa: E402
 from repro.configs import all_cells, all_skips, get_config, get_shape  # noqa: E402
 from repro.distributed.sharding import (DEFAULT_RULES, named_shardings,  # noqa: E402
                                         partition_spec)
@@ -29,7 +30,8 @@ from repro.models.common import (ArraySpec, ModelConfig, ShapeConfig,  # noqa: E
                                  abstract_params, is_spec)
 from repro.serving.engine import ServeConfig, make_decode_step, \
     make_prefill_step  # noqa: E402
-from repro.shuffle.api import ShuffleConfig  # noqa: E402
+from repro.shuffle.api import (ShuffleConfig, context_mesh,  # noqa: E402
+                               manual_axis_names)
 from repro.training.train_step import TrainConfig, make_train_step  # noqa: E402
 from repro.training.optimizer import OptConfig  # noqa: E402
 
@@ -95,10 +97,7 @@ def cell_rules(cfg: ModelConfig, shape: ShapeConfig, mesh):
 def _strip_ambient_manual(pspec):
     """Drop mesh axes that are Manual in the ambient mesh (inside a
     pod-manual shard_map the constraint must not mention "pod")."""
-    ctx = jaxcompat.get_abstract_mesh()
-    if ctx is None:
-        return pspec
-    manual = jaxcompat.manual_axis_names(ctx)
+    manual = manual_axis_names(context_mesh())
     if not manual:
         return pspec
 
@@ -289,7 +288,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *,
     t_compile = time.time() - t0
 
     ma = compiled.memory_analysis()
-    ca = jaxcompat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     stats = hlo_analysis.analyze(compiled.as_text(), num_devices=n_dev,
                                  devices_per_pod=dpp)
 

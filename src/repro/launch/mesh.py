@@ -9,11 +9,12 @@ scales to 1000+ nodes — nothing in the framework assumes pod == 2).
 
 from __future__ import annotations
 
-from repro import jaxcompat
+import jax
 
 
 def _mesh(shape, axes):
-    return jaxcompat.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

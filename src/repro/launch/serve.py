@@ -24,10 +24,12 @@ def main():
     import jax
     import jax.numpy as jnp
     from repro.configs import get_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import lm
     from repro.models.common import init_params
     from repro.serving import ServeConfig, make_decode_step
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     if not cfg.has_decode:
         raise SystemExit(f"{args.arch} is encoder-only (no decode step)")

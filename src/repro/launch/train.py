@@ -31,6 +31,7 @@ def main():
 
     import jax
     from repro.checkpoint import FileStore
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.configs import get_config
     from repro.data import lm_batch_stream
     from repro.models import lm
@@ -41,6 +42,7 @@ def main():
                                 make_train_step)
     from repro.utils import tree_num_params
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     params = init_params(lm.param_defs(cfg), jax.random.key(0))
     opt = adamw_init(params)
@@ -55,7 +57,9 @@ def main():
                                      total_steps=args.steps),
                        microbatches=args.microbatches, shuffle=shuf,
                        grad_sync=args.grad_sync)
-    step = jax.jit(make_train_step(cfg, tcfg, mesh=mesh))
+    # donated: checkpoints copy the state to the host before save returns
+    step = jax.jit(make_train_step(cfg, tcfg, mesh=mesh),
+                   donate_argnums=(0, 1))
     batch_fn = lm_batch_stream(cfg.vocab_size, args.batch, args.seq,
                                multimodal=cfg.multimodal,
                                d_model=cfg.d_model)

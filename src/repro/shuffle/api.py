@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import jaxcompat
 from repro.shuffle import dispatch as D
 
 # Public kernel surface, resolved lazily (PEP 562): the kernel packages
@@ -72,17 +71,32 @@ class ShuffleConfig:
             use_context_mesh=True)
 
 
+def context_mesh():
+    """Ambient mesh of the enclosing shard_map region, else ``None``."""
+    mesh = jax.sharding.get_abstract_mesh()
+    # outside any region this is an empty AbstractMesh
+    return mesh if mesh.axis_names else None
+
+
+def manual_axis_names(mesh) -> set:
+    """Names of the mesh axes that are Manual (shard_map'd) in ``mesh``."""
+    if mesh is None:
+        return set()
+    return {n for n, t in zip(mesh.axis_names, mesh.axis_types)
+            if t == jax.sharding.AxisType.Manual}
+
+
 def _mesh_axis_names(mesh):
     if mesh is not None:
         return mesh.axis_names
-    ctx = jaxcompat.get_abstract_mesh()
+    ctx = context_mesh()
     return ctx.axis_names if ctx is not None else ()
 
 
 def mesh_axis_size(mesh, name) -> int:
     if mesh is not None:
         return mesh.shape[name]
-    return dict(jaxcompat.get_abstract_mesh().shape)[name]
+    return dict(context_mesh().shape)[name]
 
 
 def _expert_ffn(we_gate, we_up, we_down, compute_dtype):
@@ -243,10 +257,9 @@ def ep_moe_ffn(x, w_router, we_gate, we_up, we_down, *, top_k: int,
     if cfg.use_context_mesh:
         # nested inside a pod-manual region: use the ambient mesh and make
         # manual only the axes that are not already manual in the context.
-        ctx = jaxcompat.get_abstract_mesh()
-        kwargs["axis_names"] = (set(ctx.axis_names)
-                                - jaxcompat.manual_axis_names(ctx))
-    y, aux, dropped, load, dcn = jaxcompat.shard_map(
+        ctx = context_mesh()
+        kwargs["axis_names"] = set(ctx.axis_names) - manual_axis_names(ctx)
+    y, aux, dropped, load, dcn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(cfg.token_axes, None), tok_spec, P(None, None),
                   P(cfg.expert_axes, None, None),

@@ -15,15 +15,16 @@ from repro.train_input.tokens import (TokenStreamConfig, assemble_batch,
 __all__ = [
     "ShuffleFedInput", "TokenStreamConfig", "assemble_batch",
     "decode_record", "reference_batch", "step_records", "step_tokens",
-    "SimulatedCrash", "ShuffleTrainResult", "train_shuffle_fed",
-    "input_spec_report", "validate_device_batch", "lower_train_step",
+    "SimulatedCrash", "ShuffleTrainResult", "init_train_state",
+    "train_shuffle_fed", "input_spec_report", "validate_device_batch",
+    "lower_train_step",
 ]
 
 
 def __getattr__(name):
     # loop/specs_check pull in jax + the model stack; load them lazily so
     # engine-only consumers of the pipeline stay light
-    if name in ("SimulatedCrash", "ShuffleTrainResult",
+    if name in ("SimulatedCrash", "ShuffleTrainResult", "init_train_state",
                 "train_shuffle_fed"):
         from repro.train_input import loop
         return getattr(loop, name)

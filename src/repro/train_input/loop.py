@@ -38,8 +38,10 @@ import time
 from typing import Dict, List, Optional
 
 import jax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import latest_step
+from repro.distributed.sharding import DEFAULT_RULES, named_shardings
 from repro.models import init_params, lm
 from repro.train_input.pipeline import ShuffleFedInput
 from repro.train_input.tokens import TokenStreamConfig
@@ -62,6 +64,21 @@ class ShuffleTrainResult:
     engine: object
 
 
+def init_train_state(model_cfg, seed: int = 0, mesh=None):
+    """Initial (params, adamw state). With a mesh, both are created in
+    place with the shardings ``DEFAULT_RULES`` gives each parameter; the
+    optimizer moments follow their parameter."""
+    defs = lm.param_defs(model_cfg)
+    key = jax.random.key(seed)
+    if mesh is None:
+        params = init_params(defs, key)
+        return params, adamw_init(params)
+    p_sh = named_shardings(defs, DEFAULT_RULES, mesh)
+    o_sh = {"m": p_sh, "v": p_sh, "count": NamedSharding(mesh, P())}
+    params = jax.jit(lambda k: init_params(defs, k), out_shardings=p_sh)(key)
+    return params, jax.jit(adamw_init, out_shardings=o_sh)(params)
+
+
 def train_shuffle_fed(model_cfg, tcfg, mesh, stream: TokenStreamConfig, *,
                       steps: int, engine_factory, ckpt=None,
                       ckpt_every: int = 4, resume: bool = False,
@@ -76,10 +93,13 @@ def train_shuffle_fed(model_cfg, tcfg, mesh, stream: TokenStreamConfig, *,
                                **(pipeline_kwargs or {}))
     pipeline.submit()
 
-    params = init_params(lm.param_defs(model_cfg), jax.random.key(init_seed))
-    opt = adamw_init(params)
+    params, opt = init_train_state(model_cfg, init_seed, mesh)
     if step_fn is None:
-        step_fn = jax.jit(make_train_step(model_cfg, tcfg, mesh=mesh))
+        # the step's outputs reuse its inputs' buffers; a checkpoint copies
+        # the state to the host before save() returns, so nothing reads a
+        # donated array
+        step_fn = jax.jit(make_train_step(model_cfg, tcfg, mesh=mesh),
+                          donate_argnums=(0, 1))
 
     start, offsets_checked = 0, False
     if resume:
@@ -89,7 +109,9 @@ def train_shuffle_fed(model_cfg, tcfg, mesh, stream: TokenStreamConfig, *,
         if last is None:
             raise RuntimeError("resume requested but no committed manifest")
         m = ckpt.manifest(last)
-        state = ckpt.restore(last, {"params": params, "opt": opt})
+        like = {"params": params, "opt": opt}
+        state = ckpt.restore(last, like, shardings=jax.tree.map(
+            lambda a: a.sharding, like))
         params, opt = state["params"], state["opt"]
         start = int(m["extra"]["next_step"])
         pipeline.fast_forward(start, m["extra"]["offsets"])

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import jaxcompat
 from repro.models import lm
 from repro.models.common import ModelConfig
 from repro.shuffle.api import ShuffleConfig
@@ -140,13 +139,8 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh=None,
         metrics.update(om)
         return params, opt_state, metrics
 
-    # partial-auto shard_map (manual over "pod", auto over data/model)
-    # needs the current jax.shard_map; on 0.4.x the SPMD partitioner
-    # check-fails on the manual-subgroup mix, so degrade to GSPMD auto
-    # grad sync there rather than crash.
     use_blob = (tcfg.grad_sync in ("blob", "blob_int8") and mesh is not None
-                and "pod" in mesh.axis_names and mesh.shape["pod"] > 1
-                and jaxcompat.NEW_SHARD_MAP)
+                and "pod" in mesh.axis_names and mesh.shape["pod"] > 1)
     if not use_blob:
         return plain_step
 
@@ -175,7 +169,7 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh=None,
             lambda _: P("pod") if batch_dim0 else P(), tree)
 
     def step(params, opt_state, batch):
-        return jaxcompat.shard_map(
+        return jax.shard_map(
             pod_local_step, mesh=mesh,
             in_specs=(spec_tree(params), spec_tree(opt_state),
                       spec_tree(batch, batch_dim0=True)),

@@ -6,7 +6,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro import jaxcompat
 from repro.launch import hlo_analysis as H
 
 pytestmark = pytest.mark.slow   # XLA compile sweeps: deselected in CI
@@ -31,7 +30,7 @@ def test_scan_flops_multiplied_by_trip_count():
     expect = 2 * M * K * K * L
     assert st.flops == pytest.approx(expect, rel=0.01)
     # XLA's own analysis counts the loop body once — ours must be larger
-    xla = jaxcompat.cost_analysis(comp).get("flops", 0)
+    xla = comp.cost_analysis().get("flops", 0)
     assert st.flops > xla * (L / 2)
 
 

@@ -14,13 +14,10 @@ from repro.kernels.blob_codec.ref import (compress_pack_ref,
 from repro.kernels.blob_codec.host import compress_pack_fused_host
 from repro.kernels.blob_pack.host import (blob_pack_fused_host,
                                           sorted_order_np)
-from repro.kernels.blob_pack.kernel import (SWEEP_ROW_TILES,
-                                            blob_pack_fused_pallas,
-                                            blob_pack_pallas)
+from repro.kernels.blob_pack.kernel import SWEEP_ROW_TILES, blob_pack_pallas
 from repro.kernels.blob_pack.ops import blob_pack_fused, pack_from_keys
 from repro.kernels.blob_pack.ref import blob_pack_ref
-from repro.kernels.blob_unpack.kernel import (blob_unpack_fused_pallas,
-                                              blob_unpack_pallas)
+from repro.kernels.blob_unpack.kernel import blob_unpack_pallas
 from repro.kernels.blob_unpack.ops import unpack_from_keys
 from repro.kernels.blob_unpack.ref import blob_unpack_ref
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
@@ -106,7 +103,7 @@ def test_pack_unpack_roundtrip():
     (100, 16, 4, 8, jnp.float32),       # drops (cap < demand)
     (64, 128, 8, 16, jnp.bfloat16),
     (7, 8, 3, 4, jnp.float32),          # tiny / ragged
-    (50, 8, 4, 200, jnp.float32),       # capacity > FUSED tile, uneven
+    (50, 8, 4, 200, jnp.float32),       # capacity > row tile, uneven
     (128, 64, 16, 8, jnp.int32),        # integer payload (metadata)
 ])
 def test_blob_pack_fused_matches_ref(T, d, bins, cap, dtype):
@@ -118,8 +115,8 @@ def test_blob_pack_fused_matches_ref(T, d, bins, cap, dtype):
     keys = jax.random.randint(jax.random.key(1), (T,), 0, bins)
     order, starts, counts = sorted_order(keys, bins)
     ref = blob_pack_ref(x, order, starts, counts, capacity=cap)
-    out = blob_pack_fused_pallas(x, order, starts, counts, capacity=cap,
-                                 interpret=True)
+    out = blob_pack_pallas(x, order, starts, counts, capacity=cap,
+                           interpret=True)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
     # the jit-fused front half (sort/rank + gather in one pass) agrees too
     fused, (o2, s2, c2) = blob_pack_fused(x, keys, num_bins=bins,
@@ -133,14 +130,14 @@ def test_blob_pack_fused_matches_ref(T, d, bins, cap, dtype):
     (64, 8, 16, 32),
     (33, 4, 8, 16),       # U not a multiple of the tile
     (8, 2, 4, 8),
-    (300, 4, 128, 8),     # U > FUSED tile
+    (300, 4, 128, 8),     # U > row tile
 ])
 def test_blob_unpack_fused_matches_ref(U, bins, cap, d):
     buf = jax.random.normal(jax.random.key(4), (bins, cap, d))
     slot = jax.random.randint(jax.random.key(5), (U,), 0, bins * cap)
     valid = jax.random.bernoulli(jax.random.key(6), 0.8, (U,))
     ref = blob_unpack_ref(buf, slot, valid)
-    out = blob_unpack_fused_pallas(buf, slot, valid, interpret=True)
+    out = blob_unpack_pallas(buf, slot, valid, interpret=True)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
@@ -161,7 +158,7 @@ def test_fused_pack_unpack_roundtrip():
     (64, 32, 8, 16),
     (100, 16, 4, 8),       # drops (cap < demand)
     (7, 8, 3, 4),          # tiny / ragged
-    (50, 8, 4, 200),       # capacity > FUSED tile, uneven
+    (50, 8, 4, 200),       # capacity > row tile, uneven
 ])
 def test_compress_pack_fused_matches_ref(T, d, bins, cap):
     x = jax.random.normal(jax.random.key(11), (T, d))
@@ -184,7 +181,7 @@ def test_compress_pack_fused_matches_ref(T, d, bins, cap):
 @pytest.mark.parametrize("U,bins,cap,d", [
     (64, 8, 16, 32),
     (33, 4, 8, 16),        # U not a multiple of the tile
-    (300, 4, 128, 8),      # U > FUSED tile
+    (300, 4, 128, 8),      # U > row tile
 ])
 def test_unpack_decompress_fused_matches_ref(U, bins, cap, d):
     q = jax.random.randint(jax.random.key(13), (bins, cap, d),
@@ -244,10 +241,6 @@ def test_pack_tile_geometry_edges(T, d, bins, cap, row_tile):
         out = blob_pack_pallas(x, order, starts, counts, capacity=cap,
                                interpret=True, row_tile=rt)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-        fused = blob_pack_fused_pallas(x, order, starts, counts,
-                                       capacity=cap, interpret=True,
-                                       row_tile=rt)
-        np.testing.assert_array_equal(np.asarray(fused), np.asarray(ref))
     # bins beyond the key range really are empty
     assert not np.asarray(ref)[bins // 2 + 1:].any()
 
@@ -278,9 +271,8 @@ def test_row_tile_sweep_parity():
     order, starts, counts = sorted_order(keys, bins)
     ref = blob_pack_ref(x, order, starts, counts, capacity=cap)
     for rt in SWEEP_ROW_TILES:
-        out = blob_pack_fused_pallas(x, order, starts, counts,
-                                     capacity=cap, interpret=True,
-                                     row_tile=rt)
+        out = blob_pack_pallas(x, order, starts, counts, capacity=cap,
+                               interpret=True, row_tile=rt)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 

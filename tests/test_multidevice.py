@@ -14,8 +14,6 @@ import textwrap
 
 import pytest
 
-from repro import jaxcompat
-
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 pytestmark = pytest.mark.slow   # subprocess multi-device: deselected in CI
@@ -146,9 +144,6 @@ def test_blob_pools_capacity_smaller_dcn():
     """)
 
 
-@pytest.mark.skipif(not jaxcompat.NEW_SHARD_MAP,
-                    reason="partial-auto shard_map + axis_index needs the "
-                    "current partitioner (PartitionId unimplemented on 0.4.x)")
 def test_grad_sync_exact_and_compressed():
     run_py("""
     import jax, jax.numpy as jnp, numpy as np

@@ -7,16 +7,11 @@ import textwrap
 
 import pytest
 
-from repro import jaxcompat
-
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 pytestmark = pytest.mark.slow   # subprocess multi-device: deselected in CI
 
 
-@pytest.mark.skipif(not jaxcompat.NEW_SHARD_MAP,
-                    reason="partial-auto shard_map + axis_index needs the "
-                    "current partitioner (PartitionId unimplemented on 0.4.x)")
 def test_gpipe_matches_sequential():
     code = textwrap.dedent("""
     import os
