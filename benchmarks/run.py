@@ -49,7 +49,7 @@ SUITES = {
            "TRACE_obs.json)",
     "tpu": "TPU shuffle adaptation",
     "kernels": "Pallas kernel microbenchmarks",
-    "train_input": "shuffle-fed MoE train loop: input GB/s + overlap, "
+    "train_input": "shuffle-fed MoE train loop: double-buffer overlap, "
                    "resume-after-AZ-outage bit-identity, sharded "
                    "input-spec dryrun (writes BENCH_train_input.json)",
     "dryrun": "roofline summary of results/dryrun",

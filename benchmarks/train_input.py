@@ -7,9 +7,9 @@ train_input [--quick]``):
 * **pipeline** — an uninterrupted shuffle-fed run: step-keyed records
   flow source → Batcher → blob → ExpressOneZone store → notification
   log (ElasticCluster) → Debatcher → ``ShuffleFedInput`` → sharded
-  device batches → jitted ``make_train_step``; reports input GB/s,
-  the step-time overlap fraction of the double buffer, and the loss
-  trajectory (gate: decreasing).
+  device batches → jitted ``make_train_step``; reports the overlap
+  fraction of the double buffer and the loss trajectory (gate:
+  decreasing).
 * **resume** — the same engine factory with an **AZ outage** on the
   virtual clock (every worker in AZ 1 fail-stops; partitions reassign
   cross-AZ and uncommitted notifications replay) and a ``SimulatedCrash``
@@ -55,13 +55,8 @@ FIELD_DOCS = {
     "az_outage_at_s": "virtual time when every worker in one AZ "
                       "fail-stops (partitions reassign cross-AZ, "
                       "uncommitted notifications replay)",
-    "input_gb_s": "delivered input bytes / host seconds spent advancing "
-                  "the engine (blocking wait + overlapped prefetch)",
     "overlap_fraction": "fraction of batches already staged when the "
                         "trainer asked — the double-buffer hit rate",
-    "input_wait_s": "host seconds the train step actually blocked on "
-                    "input (not absorbed by prefetch)",
-    "step_time_s_mean": "mean wall seconds per train step (compute)",
     "records_delivered": "records the engine delivered (uninterrupted "
                          "run)",
     "records_replayed": "records replayed by commit-protocol recovery "
@@ -166,8 +161,6 @@ def run(quick: bool = False) -> List[Row]:
                                              transient_p=0.05)),
                              **common)
     st = base.input_stats
-    host_s = st["host_wait_s"] + st["host_prefetch_s"]
-    input_gb_s = (st["bytes_delivered"] / host_s / 1e9) if host_s else 0.0
     losses = base.losses
     loss_decreasing = (float(np.mean(losses[-3:]))
                        < float(np.mean(losses[:3])))
@@ -213,10 +206,7 @@ def run(quick: bool = False) -> List[Row]:
         "crash_at_step": crash_at,
         "resume_step": resume_step,
         "az_outage_at_s": outage_t,
-        "input_gb_s": input_gb_s,
         "overlap_fraction": st["overlap_fraction"],
-        "input_wait_s": st["host_wait_s"],
-        "step_time_s_mean": st["step_time_s"] / max(len(base.steps), 1),
         "records_delivered": st["records_delivered"],
         "records_replayed": (broken.input_stats["records_replayed"]
                              + resumed.input_stats["records_replayed"]),
@@ -241,8 +231,8 @@ def run(quick: bool = False) -> List[Row]:
         f.write("\n")
 
     rows: List[Row] = [
-        ("train_input.pipeline", st["step_time_s"] * 1e6 / max(steps, 1),
-         f"gb_s={input_gb_s:.3f} overlap={st['overlap_fraction']:.2f} "
+        ("train_input.pipeline", 0.0,
+         f"overlap={st['overlap_fraction']:.2f} "
          f"loss {losses[0]:.3f}->{losses[-1]:.3f} "
          f"decreasing={loss_decreasing}"),
         ("train_input.resume", 0.0,

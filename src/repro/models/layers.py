@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.common import ArraySpec, ModelConfig
+from repro.obs.scopes import scope
 
 
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-6) -> jax.Array:
@@ -50,6 +51,7 @@ def mlp_defs(cfg: ModelConfig, d_ff: int, *, stacked: int = 0) -> dict:
     }
 
 
+@scope("ffn")
 def mlp_apply(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
     cd = cfg.compute_dtype
     x = x.astype(cd)

@@ -24,6 +24,7 @@ from repro.models import moe as MOE
 from repro.models import ssm as SSM
 from repro.models.common import ArraySpec, ModelConfig
 from repro.models.flash import NO_HINTS, ShardHints
+from repro.obs.scopes import scope
 from repro.shuffle.api import ShuffleConfig
 
 DENSE = ShuffleConfig(mode="dense")
@@ -97,6 +98,7 @@ def param_defs(cfg: ModelConfig) -> dict:
 # Forward (train / prefill)
 # ---------------------------------------------------------------------------
 
+@scope("head")
 def _embed_inputs(cfg: ModelConfig, params, batch) -> jax.Array:
     """Token / multimodal / stub-frontend embedding. Returns (B, S, d)."""
     if cfg.multimodal is not None and cfg.multimodal.kind == "audio":
@@ -122,6 +124,7 @@ def _sinusoidal(S: int, d: int, dtype) -> jax.Array:
     return out.astype(dtype)
 
 
+@scope("attention")
 def _attn_apply(cfg, p, x, positions, hints=NO_HINTS):
     if cfg.mla is not None:
         return MLA.mla_apply(cfg, p, x, positions=positions, hints=hints)
@@ -130,18 +133,21 @@ def _attn_apply(cfg, p, x, positions, hints=NO_HINTS):
 
 def _block_apply(cfg, p, x, positions, *, moe: bool, mesh, shuffle,
                  hints=NO_HINTS):
-    """Pre-LN transformer block. Returns (x, aux)."""
+    """Pre-LN transformer block. Returns (x, aux, units dropped to expert
+    capacity)."""
     h = _attn_apply(cfg, p["attn"],
                     L.rms_norm(x, p["ln1"], cfg.norm_eps), positions,
                     hints=hints)
     x = x + h
     z = L.rms_norm(x, p["ln2"], cfg.norm_eps)
     if moe:
-        y, aux, _ = MOE.moe_apply(cfg, p["ffn"], z, shuffle=shuffle,
-                                  mesh=mesh)
+        y, aux, diag = MOE.moe_apply(cfg, p["ffn"], z, shuffle=shuffle,
+                                     mesh=mesh)
+        dropped = diag["dropped"]
     else:
         y, aux = L.mlp_apply(cfg, p["ffn"], z), jnp.zeros((), jnp.float32)
-    return x + y, aux
+        dropped = jnp.zeros((), jnp.int32)
+    return x + y, aux, dropped
 
 
 def _ssm_block_apply(cfg, p, x):
@@ -164,8 +170,10 @@ def _remat(fn, policy: str):
 
 def forward(cfg: ModelConfig, params, batch, *, mesh=None,
             shuffle: ShuffleConfig = DENSE, remat: str = "none",
-            hints: ShardHints = NO_HINTS) -> Tuple[jax.Array, jax.Array]:
-    """Full-sequence forward. Returns (logits (B, S, V), aux_loss).
+            hints: ShardHints = NO_HINTS
+            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Full-sequence forward. Returns (logits (B, S, V), aux_loss, units
+    dropped to expert capacity summed over the MoE layers).
 
     ``hints.residual`` shards the residual stream at every block boundary
     (sequence parallelism — shards the remat-saved activations over the
@@ -177,13 +185,14 @@ def forward(cfg: ModelConfig, params, batch, *, mesh=None,
     B, S, _ = x.shape
     positions = jnp.arange(S)[None, :]
     aux_total = jnp.zeros((), jnp.float32)
+    dropped_total = jnp.zeros((), jnp.int32)
 
     if cfg.kind in ("decoder", "encoder"):
         if "dense_blocks" in params:
             def dense_body(x, p):
-                x, aux = _block_apply(cfg, p, x, positions, moe=False,
-                                      mesh=mesh, shuffle=shuffle,
-                                      hints=hints)
+                x, aux, _ = _block_apply(cfg, p, x, positions, moe=False,
+                                         mesh=mesh, shuffle=shuffle,
+                                         hints=hints)
                 return c(x), aux
             if cfg.moe.first_dense_layers == 1:
                 # size-1 scans trigger degenerate GSPMD reshards — inline
@@ -198,11 +207,14 @@ def forward(cfg: ModelConfig, params, batch, *, mesh=None,
         moe = cfg.moe is not None
 
         def body(x, p):
-            x, aux = _block_apply(cfg, p, x, positions, moe=moe,
-                                  mesh=mesh, shuffle=shuffle, hints=hints)
-            return c(x), aux
-        x, auxs = jax.lax.scan(_remat(body, remat), x, params["blocks"])
+            x, aux, dropped = _block_apply(cfg, p, x, positions, moe=moe,
+                                           mesh=mesh, shuffle=shuffle,
+                                           hints=hints)
+            return c(x), (aux, dropped)
+        x, (auxs, dropped) = jax.lax.scan(_remat(body, remat), x,
+                                          params["blocks"])
         aux_total += jnp.sum(auxs)
+        dropped_total += jnp.sum(dropped)
 
     elif cfg.kind == "ssm":
         def body(x, p):
@@ -225,17 +237,18 @@ def forward(cfg: ModelConfig, params, batch, *, mesh=None,
             x, _ = jax.lax.scan(inner, x, p_group)
             inp = jnp.concatenate([x, x0], axis=-1) if h.concat_embed else x
             z = inp.astype(cfg.compute_dtype) @ w_in.astype(cfg.compute_dtype)
-            y, _ = _block_apply(cfg, params["shared_block"], z, positions,
-                                moe=False, mesh=mesh, shuffle=shuffle,
-                                hints=hints)
+            y, _, _ = _block_apply(cfg, params["shared_block"], z,
+                                   positions, moe=False, mesh=mesh,
+                                   shuffle=shuffle, hints=hints)
             return c(x + y - z), None  # residual contribution of shared block
 
         x, _ = jax.lax.scan(_remat(group_body, remat), x,
                             (blocks, params["shared_in"]))
 
-    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = L.unembed_apply(cfg, params["embed"], x)
-    return logits, aux_total
+    with scope("head"):
+        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = L.unembed_apply(cfg, params["embed"], x)
+    return logits, aux_total, dropped_total
 
 
 # ---------------------------------------------------------------------------

@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.common import ArraySpec, ModelConfig
+from repro.obs.scopes import scope
 from repro.shuffle.api import ShuffleConfig, dense_moe_ffn, ep_moe_ffn
 
 
@@ -52,12 +53,11 @@ def moe_apply(cfg: ModelConfig, p: dict, x: jax.Array, *,
     xt = x.reshape(B * S, d)
 
     if shuffle.mode == "dense" or mesh is None:
-        y, aux, load = dense_moe_ffn(
+        y, aux, load, dropped = dense_moe_ffn(
             xt, p["router"], p["we_gate"], p["we_up"], p["we_down"],
             top_k=m.top_k, capacity_factor=m.capacity_factor,
             norm_topk=shuffle.norm_topk, compute_dtype=cd)
-        diag = {"expert_load": load,
-                "dropped": jnp.zeros((), jnp.int32),
+        diag = {"expert_load": load, "dropped": dropped,
                 "dcn_bytes": jnp.zeros((), jnp.float32)}
     else:
         # pad token count to the token-axes product
@@ -83,9 +83,10 @@ def moe_apply(cfg: ModelConfig, p: dict, x: jax.Array, *,
 
     y = y.reshape(B, S, d)
     if m.num_shared:
-        sp = p["shared"]
-        xs = x.astype(cd)
-        g = jax.nn.silu(xs @ sp["w_gate"].astype(cd))
-        u = xs @ sp["w_up"].astype(cd)
-        y = y + (g * u) @ sp["w_down"].astype(cd)
+        with scope("ffn"):
+            sp = p["shared"]
+            xs = x.astype(cd)
+            g = jax.nn.silu(xs @ sp["w_gate"].astype(cd))
+            u = xs @ sp["w_up"].astype(cd)
+            y = y + (g * u) @ sp["w_down"].astype(cd)
     return y.astype(x.dtype), aux * m.aux_loss_coef, diag

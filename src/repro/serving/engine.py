@@ -35,9 +35,9 @@ def make_prefill_step(cfg: ModelConfig, scfg: ServeConfig, mesh=None,
     hints = hints or NO_HINTS
 
     def prefill(params, batch):
-        logits, _ = lm.forward(cfg, params, batch, mesh=mesh,
-                               shuffle=scfg.shuffle, remat="none",
-                               hints=hints)
+        logits, _, _ = lm.forward(cfg, params, batch, mesh=mesh,
+                                  shuffle=scfg.shuffle, remat="none",
+                                  hints=hints)
         return logits
     return prefill
 

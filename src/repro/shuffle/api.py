@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.obs.scopes import scope
 from repro.shuffle import dispatch as D
 
 # Public kernel surface, resolved lazily (PEP 562): the kernel packages
@@ -101,6 +102,7 @@ def mesh_axis_size(mesh, name) -> int:
 
 def _expert_ffn(we_gate, we_up, we_down, compute_dtype):
     """Batched SwiGLU over (E_loc, C, d) token buffers."""
+    @scope("moe_experts")
     def fn(t):
         t = t.astype(compute_dtype)
         g = jax.nn.silu(jnp.einsum("ecd,edf->ecf", t,
@@ -130,20 +132,22 @@ def _route(x, w_router, top_k, norm_topk, num_real: Optional[int] = None):
     return sel_w, sel_idx.astype(jnp.int32), probs
 
 
+@scope("moe_dispatch")     # the expert FFN inside keeps its own scope
 def dense_moe_ffn(x, w_router, we_gate, we_up, we_down, *, top_k: int,
                   capacity_factor: float, norm_topk: bool = True,
                   compute_dtype=jnp.bfloat16):
     """Single-device capacity-based dispatch (correctness oracle).
 
-    x: (T, d). Returns (y (T, d), aux_loss scalar, expert_load (E,)).
+    x: (T, d). Returns (y (T, d), aux_loss scalar, expert_load (E,),
+    units dropped to capacity).
     """
     T, d = x.shape
     E = w_router.shape[1]
     sel_w, sel_idx, probs = _route(x, w_router, top_k, norm_topk)
     U = T * top_k
     cap = D._cap(U / E, capacity_factor)
-    from repro.shuffle.binning import bin_pack, scatter_to_bins, \
-        gather_from_bins
+    from repro.shuffle.binning import bin_pack, dropped_units, \
+        gather_from_bins, scatter_to_bins
     unit_expert = sel_idx.reshape(-1)
     unit_tok = jnp.repeat(jnp.arange(T, dtype=jnp.int32), top_k)
     pack = bin_pack(unit_expert, E, cap)
@@ -154,7 +158,7 @@ def dense_moe_ffn(x, w_router, we_gate, we_up, we_down, *, top_k: int,
                    y_units.reshape(T, top_k, d).astype(jnp.float32))
     load = pack.counts
     aux = _aux_loss(probs, load, U, E)
-    return y.astype(x.dtype), aux, load
+    return y.astype(x.dtype), aux, load, dropped_units(pack, cap)
 
 
 def _aux_loss(probs, load, total_units, E):
@@ -194,13 +198,12 @@ def ep_moe_ffn(x, w_router, we_gate, we_up, we_down, *, top_k: int,
     if cfg.use_context_mesh:
         mesh = None
     if cfg.mode == "dense" or not cfg.expert_axes:
-        y, aux, load = dense_moe_ffn(
+        y, aux, load, dropped = dense_moe_ffn(
             x, w_router, we_gate, we_up, we_down, top_k=top_k,
             capacity_factor=cfg.capacity_factor, norm_topk=cfg.norm_topk,
             compute_dtype=compute_dtype)
-        zero = jnp.zeros((), jnp.float32)
         return y, aux, D.DispatchDiagnostics(
-            jnp.zeros((), jnp.int32), load, zero)
+            dropped, load, jnp.zeros((), jnp.float32))
 
     ep_size = 1
     for a in cfg.expert_axes:
@@ -220,6 +223,7 @@ def ep_moe_ffn(x, w_router, we_gate, we_up, we_down, *, top_k: int,
     if token_mask is None:
         token_mask = jnp.ones((x.shape[0],), jnp.float32)
 
+    @scope("moe_dispatch")  # the expert FFN inside keeps its own scope
     def local_fn(x_loc, mask_loc, wr, wg, wu, wd):
         sel_w, sel_idx, probs = _route(x_loc, wr, top_k, cfg.norm_topk,
                                        num_real=E_real)

@@ -34,7 +34,6 @@ ambiguity, but not a reproducible gate.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, List, Optional
 
 import jax
@@ -43,6 +42,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import latest_step
 from repro.distributed.sharding import DEFAULT_RULES, named_shardings
 from repro.models import init_params, lm
+from repro.obs.scopes import span
 from repro.train_input.pipeline import ShuffleFedInput
 from repro.train_input.tokens import TokenStreamConfig
 from repro.training import adamw_init, make_train_step
@@ -125,7 +125,6 @@ def train_shuffle_fed(model_cfg, tcfg, mesh, stream: TokenStreamConfig, *,
 
     losses: List[float] = []
     trained: List[int] = []
-    step_time_s = 0.0
     crashed = False
     try:
         for s in range(start, steps):
@@ -133,17 +132,18 @@ def train_shuffle_fed(model_cfg, tcfg, mesh, stream: TokenStreamConfig, *,
             assert got == s, f"pipeline served {got}, trainer at {s}"
             if crash_at_step is not None and s == crash_at_step:
                 raise SimulatedCrash(f"injected crash mid-step {s}")
-            t0 = time.perf_counter()
-            params, opt, metrics = step_fn(params, opt, batch)
-            loss = float(metrics["loss"])       # blocks on the step
-            step_time_s += time.perf_counter() - t0
+            with span("train.dispatch"):
+                params, opt, metrics = step_fn(params, opt, batch)
+            with span("train.sync"):
+                loss = float(metrics["loss"])   # blocks on the step
             losses.append(loss)
             trained.append(s)
             if ckpt is not None and (s + 1) % ckpt_every == 0:
-                pipeline.commit(s + 1)
-                ckpt.save(s + 1, {"params": params, "opt": opt},
-                          extra={"next_step": s + 1,
-                                 "offsets": pipeline.offsets()})
+                with span("train.checkpoint"):
+                    pipeline.commit(s + 1)
+                    ckpt.save(s + 1, {"params": params, "opt": opt},
+                              extra={"next_step": s + 1,
+                                     "offsets": pipeline.offsets()})
     except SimulatedCrash:
         crashed = True     # process "dies": no final commit, no drain
 
@@ -168,9 +168,6 @@ def train_shuffle_fed(model_cfg, tcfg, mesh, stream: TokenStreamConfig, *,
         "prefetch_hits": pipeline.prefetch_hits,
         "overlap_fraction": (pipeline.prefetch_hits / pipeline.requests
                              if pipeline.requests else 0.0),
-        "host_wait_s": pipeline.host_wait_s,
-        "host_prefetch_s": pipeline.host_prefetch_s,
-        "step_time_s": step_time_s,
     }
     return ShuffleTrainResult(start, trained, losses, crashed,
                               offsets_checked, stats, pipeline, engine)

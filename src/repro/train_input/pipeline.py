@@ -33,12 +33,12 @@ Three properties the training loop leans on:
 
 from __future__ import annotations
 
-import time
 from collections import Counter, defaultdict
 from typing import Dict, Optional, Set, Tuple
 
 import numpy as np
 
+from repro.obs.scopes import span
 from repro.train_input.tokens import (TokenStreamConfig, assemble_batch,
                                       decode_record, step_records)
 
@@ -74,8 +74,6 @@ class ShuffleFedInput:
         self.duplicate_rows = 0     # engine replays filtered by (step,row)
         self.late_rows = 0          # rows for already-committed steps
         self.skipped_rows = 0       # committed prefix dropped on resume
-        self.host_wait_s = 0.0      # blocking collect time the step sees
-        self.host_prefetch_s = 0.0  # overlapped advance time
         self._put = (self._make_device_put(mesh, model_cfg, rules)
                      if mesh is not None else None)
 
@@ -129,17 +127,17 @@ class ShuffleFedInput:
                         f"({have}/{self.stream.batch} rows staged)")
                 return
             self._horizon = max(self._horizon, loop.now) + self.time_slice_s
-            loop.run(until=self._horizon)
-            self._drain()
+            with span("input.advance"):
+                loop.run(until=self._horizon)
+            with span("input.drain"):
+                self._drain()
 
     def prefetch(self) -> None:
         """Advance the clock until ``prefetch_steps`` future steps are
         staged — the input runs ahead of training on the virtual clock."""
-        t0 = time.perf_counter()
         target = min(self._next + self.prefetch_steps - 1, self.steps - 1)
         for s in range(self._next, target + 1):
             self._advance(s, strict=False)
-        self.host_prefetch_s += time.perf_counter() - t0
 
     def next_batch(self):
         """The next step's batch: ``(step, batch, prefetched)``.
@@ -151,20 +149,21 @@ class ShuffleFedInput:
         s = self._next
         if s >= self.steps:
             raise StopIteration(f"stream exhausted at step {self.steps}")
-        self.requests += 1
-        hit = self._complete(s)
-        if hit:
-            self.prefetch_hits += 1
-        else:
-            t0 = time.perf_counter()
-            self._advance(s, strict=True)
-            self.host_wait_s += time.perf_counter() - t0
-        rows = self._staged.pop(s)
-        batch = assemble_batch(self.stream, rows)
-        self._next = s + 1
-        self.prefetch()
-        if self._put is not None:
-            batch = self._put(batch)
+        with span("input.next_batch"):
+            self.requests += 1
+            hit = self._complete(s)
+            if hit:
+                self.prefetch_hits += 1
+            else:
+                self._advance(s, strict=True)
+            rows = self._staged.pop(s)
+            with span("input.assemble"):
+                batch = assemble_batch(self.stream, rows)
+            self._next = s + 1
+            self.prefetch()
+            if self._put is not None:
+                with span("input.device_put"):
+                    batch = self._put(batch)
         return s, batch, hit
 
     # -- commit / resume ----------------------------------------------------

@@ -8,6 +8,8 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs.scopes import scope
+
 PyTree = Any
 
 
@@ -49,6 +51,7 @@ def global_norm(tree: PyTree) -> jax.Array:
         for x in jax.tree.leaves(tree)))
 
 
+@scope("optimizer")
 def adamw_update(cfg: OptConfig, grads: PyTree, opt_state: dict,
                  params: PyTree) -> Tuple[PyTree, dict, dict]:
     count = opt_state["count"] + 1

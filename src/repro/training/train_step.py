@@ -22,6 +22,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.models import lm
 from repro.models.common import ModelConfig
+from repro.obs.scopes import scope
 from repro.shuffle.api import ShuffleConfig
 from repro.shuffle import grad_sync as GS
 from repro.training.optimizer import OptConfig, adamw_update
@@ -40,6 +41,7 @@ class TrainConfig:
     z_loss: float = 0.0
 
 
+@scope("head")
 def cross_entropy(logits: jax.Array, labels: jax.Array,
                   z_loss: float = 0.0) -> jax.Array:
     """Mean CE over labels != IGNORE. logits (B,S,V) any dtype; fp32 math."""
@@ -81,11 +83,12 @@ def make_loss_fn(cfg: ModelConfig, tcfg: TrainConfig, mesh=None,
 
     def loss_fn(params, batch):
         params = cast_compute_params(cfg, params)
-        logits, aux = lm.forward(cfg, params, batch, mesh=mesh,
-                                 shuffle=tcfg.shuffle, remat=tcfg.remat,
-                                 hints=hints)
+        logits, aux, dropped = lm.forward(
+            cfg, params, batch, mesh=mesh, shuffle=tcfg.shuffle,
+            remat=tcfg.remat, hints=hints)
         ce = cross_entropy(logits, batch["labels"], tcfg.z_loss)
-        return ce + aux, {"loss": ce, "aux_loss": aux}
+        return ce + aux, {"loss": ce, "aux_loss": aux,
+                          "dropped_units": dropped}
     return loss_fn
 
 
@@ -115,11 +118,14 @@ def _grads(loss_fn, params, batch, microbatches: int):
 
     g0 = jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), params)
     m0 = {"loss": jnp.zeros((), jnp.float32),
-          "aux_loss": jnp.zeros((), jnp.float32)}
+          "aux_loss": jnp.zeros((), jnp.float32),
+          "dropped_units": jnp.zeros((), jnp.int32)}
     (grads, metrics), _ = jax.lax.scan(body, (g0, m0), micro)
     inv = 1.0 / microbatches
+    # losses are means over the microbatches, dropped units their sum
     return (jax.tree.map(lambda x: x * inv, grads),
-            jax.tree.map(lambda x: x * inv, metrics))
+            {k: v if k == "dropped_units" else v * inv
+             for k, v in metrics.items()})
 
 
 def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh=None,
@@ -156,8 +162,9 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh=None,
         grads, _ = GS.blob_allreduce_grads(
             grads, pod_axis="pod", blob_bytes=tcfg.grad_sync_blob_bytes,
             compress=compress, average=True)
-        metrics = jax.tree.map(
-            lambda x: jax.lax.pmean(x, "pod"), metrics)
+        metrics = {k: (jax.lax.psum if k == "dropped_units"
+                       else jax.lax.pmean)(v, "pod")
+                   for k, v in metrics.items()}
         params, opt_state, om = adamw_update(tcfg.opt, grads, opt_state,
                                              params)
         metrics.update(om)
@@ -175,6 +182,7 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh=None,
                       spec_tree(batch, batch_dim0=True)),
             out_specs=(spec_tree(params), spec_tree(opt_state),
                        jax.tree.map(lambda _: P(), {"loss": 0, "aux_loss": 0,
+                                                    "dropped_units": 0,
                                                     "grad_norm": 0,
                                                     "lr": 0})),
             check_vma=False,

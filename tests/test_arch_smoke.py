@@ -39,10 +39,11 @@ def test_forward_smoke(arch):
     params = init_params(lm.param_defs(cfg), jax.random.key(0))
     B, S = 2, 32
     batch = make_batch(cfg, B, S, jax.random.key(1))
-    logits, aux = lm.forward(cfg, params, batch)
+    logits, aux, dropped = lm.forward(cfg, params, batch)
     assert logits.shape == (B, S, cfg.vocab_size)
     assert not jnp.isnan(logits).any()
     assert not jnp.isnan(aux)
+    assert int(dropped) >= 0
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)

@@ -48,7 +48,7 @@ def test_moe_dispatch_modes_agree():
     wd = jax.random.normal(ks[4], (E, de, d)) / jnp.sqrt(de)
 
     # capacity high enough that nothing drops -> all modes exact-equal
-    y_ref, aux_ref, _ = dense_moe_ffn(x, wr, wg, wu, wd, top_k=k,
+    y_ref, aux_ref, _, _ = dense_moe_ffn(x, wr, wg, wu, wd, top_k=k,
                                       capacity_factor=16.0,
                                       compute_dtype=jnp.float32)
     outs = {}
@@ -88,7 +88,7 @@ def test_moe_dispatch_gradients_agree():
     wd = jax.random.normal(ks[4], (E, de, d)) / jnp.sqrt(de)
 
     def loss_dense(x, wr, wg, wu, wd):
-        y, aux, _ = dense_moe_ffn(x, wr, wg, wu, wd, top_k=k,
+        y, aux, _, _ = dense_moe_ffn(x, wr, wg, wu, wd, top_k=k,
                                   capacity_factor=16.0,
                                   compute_dtype=jnp.float32)
         return jnp.sum(jnp.tanh(y)) + aux
