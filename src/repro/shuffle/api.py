@@ -146,16 +146,12 @@ def dense_moe_ffn(x, w_router, we_gate, we_up, we_down, *, top_k: int,
     sel_w, sel_idx, probs = _route(x, w_router, top_k, norm_topk)
     U = T * top_k
     cap = D._cap(U / E, capacity_factor)
-    from repro.shuffle.binning import bin_pack, dropped_units, \
-        gather_from_bins, scatter_to_bins
-    unit_expert = sel_idx.reshape(-1)
-    unit_tok = jnp.repeat(jnp.arange(T, dtype=jnp.int32), top_k)
-    pack = bin_pack(unit_expert, E, cap)
-    ebuf = scatter_to_bins(x[unit_tok], pack, E, cap)      # (E, cap, d)
+    from repro.shuffle.binning import bin_pack, dropped_units, from_bins, \
+        to_bins
+    pack = bin_pack(sel_idx.reshape(-1), E, cap)
+    ebuf = to_bins(x, pack)                                 # (E, cap, d)
     eout = _expert_ffn(we_gate, we_up, we_down, compute_dtype)(ebuf)
-    y_units = gather_from_bins(eout, pack)                  # (U, d)
-    y = jnp.einsum("tk,tkd->td", sel_w,
-                   y_units.reshape(T, top_k, d).astype(jnp.float32))
+    y = from_bins(eout, pack, sel_w)                        # (T, d) f32
     load = pack.counts
     aux = _aux_loss(probs, load, U, E)
     return y.astype(x.dtype), aux, load, dropped_units(pack, cap)

@@ -31,10 +31,7 @@ from typing import Callable, NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.shuffle.binning import (bin_pack,
-                                   dropped_units,
-                                   gather_from_bins,
-                                   scatter_to_bins)
+from repro.shuffle.binning import bin_pack, dropped_units, from_bins, to_bins
 from repro.shuffle import compression
 
 
@@ -83,14 +80,11 @@ def flat_dispatch_combine(
     E_loc = num_experts // ep
     U = T_loc * k
 
-    unit_expert = sel_idx.reshape(-1)
-    unit_tok = jnp.repeat(jnp.arange(T_loc, dtype=jnp.int32), k)
-
     # Per-(source, expert) lane capacity — fine-grained, worst-case slack.
     cap = _cap(U / num_experts, capacity_factor)
-    pack = bin_pack(unit_expert, num_experts, cap)
+    pack = bin_pack(sel_idx.reshape(-1), num_experts, cap)
 
-    send = scatter_to_bins(x[unit_tok], pack, num_experts, cap)
+    send = to_bins(x, pack)
     send = send.reshape(ep, E_loc * cap, d)
     recv = _a2a(send, tuple(ep_axes))                       # (ep, E_loc*cap, d)
     recv = recv.reshape(ep, E_loc, cap, d).transpose(1, 0, 2, 3) \
@@ -102,10 +96,7 @@ def flat_dispatch_combine(
         .reshape(ep, E_loc * cap, d_out)
     back = _a2a(back, tuple(ep_axes))
     back = back.reshape(num_experts, cap, d_out)
-    y_units = gather_from_bins(back, pack)                  # (U, d_out)
-
-    y = jnp.einsum("tk,tkd->td", sel_w,
-                   y_units.reshape(T_loc, k, d_out).astype(jnp.float32))
+    y = from_bins(back, pack, sel_w)                        # (T_loc, d_out)
 
     # notifications → diagnostics
     counts_global = jax.lax.psum(pack.counts, tuple(ep_axes))
@@ -152,7 +143,6 @@ def blob_dispatch_combine(
     U = T_loc * k
 
     unit_expert = sel_idx.reshape(-1)
-    unit_tok = jnp.repeat(jnp.arange(T_loc, dtype=jnp.int32), k)
 
     # expert e lives at (pod p, model m, local l):
     #   p = e // (M*E_loc);  m = (e // E_loc) % M;  l = e % E_loc
@@ -161,8 +151,8 @@ def blob_dispatch_combine(
     # ---- Stage 1: intra-pod exchange over the model axis (cheap ICI).
     cap1 = _cap(U / M, capacity_factor)
     pack1 = bin_pack(dest_m, M, cap1)
-    payload1 = scatter_to_bins(x[unit_tok], pack1, M, cap1)
-    meta1 = scatter_to_bins(unit_expert + 1, pack1, M, cap1)  # 0 == empty
+    payload1 = to_bins(x, pack1)
+    meta1 = to_bins(unit_expert + 1, pack1)       # 0 == empty
     recv1 = _a2a(payload1, tuple(inner_axes))     # (M, cap1, d)
     rmeta1 = _a2a(meta1, tuple(inner_axes))       # (M, cap1)
 
@@ -179,8 +169,8 @@ def blob_dispatch_combine(
     cf2 = pooled_capacity_factor(capacity_factor, M)
     cap2 = _cap(U / P, cf2)
     pack2 = bin_pack(dest_p.astype(jnp.int32), P + 1, cap2)
-    payload2 = scatter_to_bins(u1_x, pack2, P + 1, cap2)[:P]
-    meta2 = scatter_to_bins(u1_expert + 1, pack2, P + 1, cap2)[:P]
+    payload2 = to_bins(u1_x, pack2)[:P]
+    meta2 = to_bins(u1_expert + 1, pack2)[:P]
 
     if compress_dcn:
         q, scale = compression.int8_quantize(payload2)
@@ -202,25 +192,22 @@ def blob_dispatch_combine(
     cf3 = pooled_capacity_factor(capacity_factor, M * P)
     cap_e = _cap(U / E_loc, cf3)
     pack3 = bin_pack(local_e.astype(jnp.int32), E_loc + 1, cap_e)
-    ebuf = scatter_to_bins(u2_x, pack3, E_loc + 1, cap_e)[:E_loc]
+    ebuf = to_bins(u2_x, pack3)[:E_loc]
 
     eout = expert_fn(ebuf)                        # (E_loc, cap_e, d_out)
 
     # ---- Reverse path (slots are symmetric; results ride the same lanes)
     eout_full = jnp.concatenate(
         [eout, jnp.zeros((1, cap_e, d_out), eout.dtype)], axis=0)
-    y2 = gather_from_bins(eout_full, pack3)       # (P*cap2, d_out)
+    y2 = from_bins(eout_full, pack3)              # (P*cap2, d_out)
     back2 = y2.reshape(P, cap2, d_out)
     back2 = _a2a(back2, (pod_axis,))
     y1_full = jnp.concatenate(
         [back2, jnp.zeros((1, cap2, d_out), back2.dtype)], axis=0)
-    y1 = gather_from_bins(y1_full, pack2)         # (M*cap1, d_out)
+    y1 = from_bins(y1_full, pack2)                # (M*cap1, d_out)
     back1 = y1.reshape(M, cap1, d_out)
     back1 = _a2a(back1, tuple(inner_axes))
-    y_units = gather_from_bins(back1, pack1)      # (U, d_out)
-
-    y = jnp.einsum("tk,tkd->td", sel_w,
-                   y_units.reshape(T_loc, k, d_out).astype(jnp.float32))
+    y = from_bins(back1, pack1, sel_w)            # (T_loc, d_out)
 
     all_axes = tuple(inner_axes) + (pod_axis,)
     counts_global = jax.lax.psum(

@@ -59,8 +59,8 @@ def test_pack_from_keys_consistent_with_binning():
     buf, (order, starts, counts) = pack_from_keys(
         x, keys, num_bins=4, capacity=32, use_pallas=True)
     pack = bin_pack(keys, 4, 32)
-    from repro.shuffle.binning import scatter_to_bins
-    expect = scatter_to_bins(x, pack, 4, 32)
+    from repro.shuffle.binning import to_bins
+    expect = to_bins(x, pack)
     np.testing.assert_allclose(np.asarray(buf), np.asarray(expect))
 
 
