@@ -50,7 +50,8 @@ class Cell:
 #: know would be ignored, so it is refused
 TRAFFIC_KEYS = {"about", "batch", "seq_len", "mesh", "moe_mode",
                 "expert_axes", "capacity_factor", "engine", "pipeline",
-                "opt", "warm_steps", "compared_steps", "trace_steps"}
+                "opt", "warm_steps", "ahead_steps", "compared_steps",
+                "trace_steps"}
 
 
 def _read_json(path: pathlib.Path):

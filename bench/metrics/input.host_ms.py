@@ -1,7 +1,6 @@
-"""input.host_ms: mean host time per traced step from the end of one step
-to the start of the next, the harness span ``bench.next_batch``: the
-loop's ``float(loss)`` of a finished step and ``next_batch``, which
-advances the shuffle engine and assembles the batch."""
+"""input.host_ms: mean host time per traced step in the harness span
+``bench.next_batch``: the pipeline's ``next_batch``, which advances the
+shuffle engine and assembles the batch while earlier steps run."""
 
 
 def read(ctx):

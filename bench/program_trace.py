@@ -7,7 +7,8 @@ The program marks its train step and its input pipeline for the profiler
 - device scopes (``jax.named_scope``) carry a layer name into each HLO
   instruction's metadata; ``layer_of`` maps instruction names, which the
   device trace gives its operations, to those layers, from the compiled
-  step's HLO text (``repro.obs.scopes.layer_of_ops``);
+  step's HLO text (``layer_of_ops``, a copy of the program's
+  ``repro.obs.scopes.layer_of_ops`` kept here with the yardstick);
 - host spans (``jax.profiler.TraceAnnotation``) named ``input.*`` and
   ``train.*``, one per call, on the trace's one clock;
 - a counter: the step's ``dropped_units`` output, the routed units that
@@ -28,14 +29,17 @@ and those records, ``metrics`` gives, per traced step:
   the harness's ``bench.block_loss``, which blocks first);
 - ``moe.dropped_share``: dropped over routed units of the traced steps, %.
 
-``record_trace.py`` runs a cell with the trace on and keeps what this
-reads; ``tests/data/program_trace.*`` is one such recording.
+A traced run (``run.py --trace 1``) keeps what this reads and the numbers
+of ``metrics`` in the readers' ``ctx`` (``run.read_trace``), and ``read``
+gives each reader of ``metrics/`` its number; ``record_trace.py`` writes the same to files,
+and ``tests/data/program_trace.*`` is one such recording.
 """
 
 from __future__ import annotations
 
 import bisect
-from collections import defaultdict
+import re
+from collections import Counter, defaultdict
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from trace_reduce import WINDOW, Reduced, length
@@ -47,6 +51,8 @@ PREFIXES = ("input.", "train.")
 #: device layers of the program's scopes, and what lies outside them
 LAYERS = ("attention", "moe_dispatch", "moe_experts", "ffn", "head",
           "optimizer", "other")
+#: the program's scope names, the layers but ``other``
+SCOPES = LAYERS[:-1]
 #: host spans in which the device waits on a step that has finished
 WAIT = ("train.sync", "bench.block_loss")
 #: {metric: host spans whose time it adds up}
@@ -67,6 +73,74 @@ def program_spans(profile) -> List[Span]:
                         out.append((e.name, e.start_ns,
                                     e.start_ns + e.duration_ns))
     return out
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$")
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
+_CALLS = re.compile(r"calls=%?([\w.\-]+)")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_WORD = re.compile(r"\w+")
+
+
+def _layer_in(op_name: str):
+    """The last scope name among the scopes of an ``op_name`` path: the
+    words of every component but the last, which names the primitive (a
+    parameter's ``op_name`` is its argument path, with no scope)."""
+    scopes = op_name.split("/")[:-1]
+    words = [w for c in scopes for w in _WORD.findall(c) if w in SCOPES]
+    return words[-1] if words else None
+
+
+def _parse(hlo_text: str) -> Dict[str, List[Tuple[str, object, object]]]:
+    """{computation: [(instruction, layer from its metadata or None,
+    computation it calls or None)]}."""
+    comps: Dict[str, list] = {}
+    body = None
+    for line in hlo_text.splitlines():
+        if body is None:
+            m = _COMPUTATION.match(line)
+            if m:
+                body = comps.setdefault(m.group(1), [])
+            continue
+        if line.startswith("}"):
+            body = None
+            continue
+        m = _INSTRUCTION.match(line)
+        if m:
+            op = _OP_NAME.search(line)
+            calls = _CALLS.search(line)
+            body.append((m.group(1), _layer_in(op.group(1)) if op else None,
+                         calls.group(1) if calls else None))
+    return comps
+
+
+def layer_of_ops(hlo_text: str) -> Dict[str, str]:
+    """{instruction name: layer} of every instruction of a compiled HLO
+    module's text (``Compiled.as_text()``).
+
+    An instruction's layer is the last scope name in its
+    ``metadata={op_name=...}`` path. One whose metadata names none (a
+    fusion, a call) takes the most common layer among the instructions of
+    the computation it calls; everything else is ``other``."""
+    comps = _parse(hlo_text)
+    memo: Dict[str, object] = {}
+
+    def majority(comp: str):
+        if comp not in memo:
+            memo[comp] = None       # a computation never calls itself
+            votes = Counter(resolve(layer, calls)
+                            for _, layer, calls in comps.get(comp, ()))
+            votes.pop(None, None)
+            memo[comp] = votes.most_common(1)[0][0] if votes else None
+        return memo[comp]
+
+    def resolve(layer, calls):
+        if layer is None and calls is not None:
+            return majority(calls)
+        return layer
+
+    return {name: resolve(layer, calls) or "other"
+            for body in comps.values() for name, layer, calls in body}
 
 
 def instruction(op: str) -> str:
@@ -145,7 +219,16 @@ def metrics(red: Reduced, spans: Sequence[Span], layer_of: Dict[str, str],
         v for k, v in idle.items() if k.startswith("input.")) / steps * 1e3
     out["device.idle_wait_ms"] = sum(idle.get(k, 0.0)
                                      for k in WAIT) / steps * 1e3
-    if dropped is not None and routed_units:
+    if dropped and routed_units:
         out["moe.dropped_share"] = (sum(dropped) * 100.0
                                     / (routed_units * len(dropped)))
     return out
+
+
+def read(ctx, metric: str) -> Optional[float]:
+    """``metric``, one of ``metrics``' numbers, from a traced run's reader
+    context: ``ctx["program"]``, the numbers ``run.read_trace`` works out
+    once per run. None where ``ctx`` has no ``program`` or it lacks the
+    metric (``moe.dropped_share`` of a step with no routed units)."""
+    program = ctx.get("program")
+    return None if program is None else program.get(metric)

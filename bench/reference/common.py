@@ -1,11 +1,14 @@
 """Plain float32 reference of a shuffle-fed MoE train step, in jax.numpy.
 
 It imports nothing of the program under test. A model type's module
-(``deepseek_v2.py``, ``qwen2_moe.py``) gives the parameter layout and the
-attention of one block; this module holds the rest: the initialisation
-from the seed, RMSNorm, rotary embeddings, causal attention, the routed
-and shared experts with the capacity rule, cross entropy with the
-load-balance loss, and AdamW.
+(``deepseek_v2.py``, ``qwen2_moe.py``) gives the parameter layout, the
+attention of one block and its MoE rule (``MoESpec``): the router, the
+router state kept between steps and how it follows the experts' loads,
+and which of the routed experts are held here. This module holds the
+rest: the initialisation from the seed, RMSNorm, rotary embeddings,
+causal attention, top-k selection with the capacity rule, the held
+experts and the shared ones, cross entropy with the load-balance loss,
+and AdamW.
 
 Every matmul runs at float32 with ``precision="highest"``. The control of
 ``correct`` is the same reference with each matmul operand rounded to
@@ -23,7 +26,8 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any, Callable, Dict, List, NamedTuple, Optional
+from typing import (Any, Callable, Dict, FrozenSet, List, NamedTuple,
+                    Optional, Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -38,9 +42,11 @@ BLOCK_ROWS = 1
 class Leaf(NamedTuple):
     """One parameter: shape, initialisation and the fan-in that scales it.
 
-    ``init``: ``zeros``, ``small`` (normal x 0.02) or ``normal`` (normal /
-    sqrt(fan_in)). Leaves are drawn in the sorted order of their dict
-    keys, one key each from ``jax.random.split(key(seed), n_leaves)``."""
+    ``init``: ``zeros``, ``small`` (normal x 0.02), ``normal`` (normal /
+    sqrt(fan_in)) or ``state``: zeros, kept between steps but not trained
+    (no gradient, no AdamW), the router state of ``ROUTER_STATE``. Leaves
+    are drawn in the sorted order of their dict keys, one key each from
+    ``jax.random.split(key(seed), n_leaves)``."""
     shape: tuple
     init: str
     fan_in: int = 1
@@ -48,6 +54,12 @@ class Leaf(NamedTuple):
 
 def is_leaf(x) -> bool:
     return isinstance(x, Leaf)
+
+
+def trained_mask(layout):
+    """The layout's tree with True on each trained leaf, False on state."""
+    return jax.tree.map(lambda leaf: leaf.init != "state", layout,
+                        is_leaf=is_leaf)
 
 
 def init_params_fn(layout):
@@ -58,7 +70,7 @@ def init_params_fn(layout):
         keys = jax.random.split(key, len(leaves))
         vals = []
         for leaf, k in zip(leaves, keys):
-            if leaf.init == "zeros":
+            if leaf.init in ("zeros", "state"):
                 vals.append(jnp.zeros(leaf.shape, jnp.float32))
                 continue
             scale = 0.02 if leaf.init == "small" else \
@@ -172,14 +184,61 @@ def swiglu(es, x, w_gate, w_up, w_down):
 
 # --- mixture of experts -----------------------------------------------------
 
+#: key, under a MoE block's ``ffn``, of the router state (``Leaf`` init
+#: ``state``), one row per MoE layer
+ROUTER_STATE = "router_state"
+
+
 @dataclasses.dataclass(frozen=True)
 class MoESpec:
-    num_experts: int
+    """A model type's MoE rule, with the run's capacity.
+
+    ``router(xp, logits, state)`` takes one layer's float32 router logits
+    (T, num_experts), with ``xp`` numpy on the host (``route``) and
+    jax.numpy in the differentiated step (``moe_ffn``), and the layer's
+    router state (None without one). It returns ``(scores, combine,
+    probs)``: the scores by which the ``top_k`` experts are chosen (a
+    selection bias from the state is added to these only); ``combine(sel)``,
+    the differentiable (T, top_k) weights of the chosen experts ``sel``
+    after the type's normalisation and scaling; and the probabilities the
+    load-balance loss takes, or None for no such loss.
+
+    ``update(state, load)``, numpy, gives a layer's router state after a
+    step from that step's expert loads (``route``); None keeps it.
+
+    ``held``: the contiguous range [start, stop) of the routed experts
+    whose weights are in the layout and whose kept units add to the
+    output (None: all). Routing, capacity and the load-balance loss cover
+    every routed expert; the absent experts' part is left out."""
+    num_experts: int           # routed over: the router's width
     top_k: int
-    norm_topk: bool
+    router: Callable
     capacity_factor: float
     capacity_groups: int       # token shards that each get their own capacity
     aux_coef: float
+    update: Optional[Callable] = None
+    held: Optional[Tuple[int, int]] = None
+
+
+def softmax_router(norm_topk: bool) -> Callable:
+    """Softmax scoring and greedy top-k, with no router state and no
+    scaling: experts are chosen by probability; the combine weights are
+    the chosen probabilities, over their sum where ``norm_topk``; the
+    load-balance loss takes the probabilities."""
+    def router(xp, logits, state):
+        if xp is np:
+            z = logits - logits.max(-1, keepdims=True)
+            probs = np.exp(z) / np.exp(z).sum(-1, keepdims=True)
+        else:
+            probs = jax.nn.softmax(logits, axis=-1)
+
+        def combine(sel):
+            w = xp.take_along_axis(probs, sel, axis=-1)
+            if norm_topk:
+                w = w / xp.maximum(xp.sum(w, -1, keepdims=True), 1e-9)
+            return w
+        return probs, combine, probs
+    return router
 
 
 def capacity(units: int, experts: int, factor: float, align: int = 8) -> int:
@@ -188,9 +247,10 @@ def capacity(units: int, experts: int, factor: float, align: int = 8) -> int:
     return max(align, -(-c // align) * align)
 
 
-def route(moe: MoESpec, logits: np.ndarray):
+def route(moe: MoESpec, logits: np.ndarray, state=None):
     """Top-k routing of the whole batch on the host, from float32 router
-    logits (T, E). Returns (sel_idx (T, k), keep (T, k), load (E,)).
+    logits (T, E) and the layer's router state, by the type's selection
+    scores. Returns (sel_idx (T, k), keep (T, k), load (E,)).
 
     Units are (token, choice) in token-major order. The tokens fall into
     ``capacity_groups`` equal contiguous shards; within a shard, a unit is
@@ -198,10 +258,9 @@ def route(moe: MoESpec, logits: np.ndarray):
     ``load`` counts every choice, kept or not."""
     T, E = logits.shape
     k = moe.top_k
-    z = logits - logits.max(-1, keepdims=True)
-    probs = np.exp(z) / np.exp(z).sum(-1, keepdims=True)
-    # highest probability first, lower expert index first on ties
-    sel = np.argsort(-probs, axis=-1, kind="stable")[:, :k].astype(np.int32)
+    scores, _, _ = moe.router(np, logits, state)
+    # highest score first, lower expert index first on ties
+    sel = np.argsort(-scores, axis=-1, kind="stable")[:, :k].astype(np.int32)
     G = moe.capacity_groups
     per = T // G
     cap = capacity(per * k, E, moe.capacity_factor)
@@ -218,21 +277,24 @@ def route(moe: MoESpec, logits: np.ndarray):
 
 def moe_ffn(es, moe: MoESpec, p, x, sel, keep):
     """Routed experts of one block of tokens. x: (T, d) float32; sel, keep:
-    (T, k) from ``route``. Each kept unit adds prob x SwiGLU_e(x); the
-    experts run eight at a time over all tokens, weighted by the unit's
-    probability where the token chose that expert and by 0 elsewhere."""
+    (T, k) from ``route``. Each kept unit on a held expert adds its
+    combine weight x SwiGLU_e(x); the held experts run eight at a time
+    over all tokens, weighted by the unit's combine weight where the
+    token chose that expert and by 0 elsewhere. Returns the output and
+    the router's probabilities for the load-balance loss (or None)."""
     E = moe.num_experts
     logits = es("td,de->te", x, p["router"])
-    probs = jax.nn.softmax(logits, axis=-1)
-    w = jnp.take_along_axis(probs, sel, axis=-1)
-    if moe.norm_topk:
-        w = w / jnp.maximum(jnp.sum(w, -1, keepdims=True), 1e-9)
-    w = w * keep
-    # (T, E) combine weights
+    state = p.get(ROUTER_STATE)
+    _, combine, probs = moe.router(
+        jnp, logits, None if state is None else jax.lax.stop_gradient(state))
+    w = combine(sel) * keep
+    # (T, E) combine weights, then the held experts' columns
     cw = jnp.zeros((x.shape[0], E), jnp.float32).at[
         jnp.arange(x.shape[0])[:, None], sel].add(w)
-    chunk = math.gcd(E, 8)
-    n = E // chunk
+    if moe.held is not None:
+        cw = cw[:, moe.held[0]:moe.held[1]]
+    chunk = math.gcd(cw.shape[1], 8)
+    n = cw.shape[1] // chunk
 
     def body(y, xs):
         wg, wu, wd, c = xs
@@ -290,7 +352,8 @@ def _moe_block(m: Model, es, p, x, sel, keep):
     if "shared" in f:
         s = f["shared"]
         y = y + swiglu(es, z, s["w_gate"], s["w_up"], s["w_down"])
-    return x + y.reshape(B, S, d), jnp.sum(probs, axis=0)
+    return x + y.reshape(B, S, d), \
+        None if probs is None else jnp.sum(probs, axis=0)
 
 
 def _embed(params, tokens):
@@ -330,7 +393,8 @@ def block_loss(m: Model, num: Numerics, params, tokens, labels, routes,
         x, psum = jax.checkpoint(
             lambda p, x, s, k: _moe_block(m, es, p, x, s, k))(
             _block_params(params, "blocks", i), x, sel, keep)
-        aux = aux + aux_loss(m.moe, psum, loads[i], n_tok)
+        if psum is not None:
+            aux = aux + aux_loss(m.moe, psum, loads[i], n_tok)
     x = rms_norm(x, params["final_norm"], m.eps)
     logits = es("bsd,dv->bsv", x, params["embed"]["unembed"])
     lse = jax.scipy.special.logsumexp(logits, axis=-1)
@@ -385,8 +449,12 @@ class Steps:
     grad_norms: np.ndarray      # per leaf, first gradient after clipping
     change_norms: np.ndarray    # per leaf, |p_last - p_0|
     leaf_names: List[str]
-    grads: Optional[Dict[str, np.ndarray]] = None
+    grads: Optional[Dict[str, np.ndarray]] = None   # trained leaves only
     change: Optional[Dict[str, np.ndarray]] = None
+    #: leaves kept but not trained: no gradient (norm 0, not in ``grads``)
+    state_leaves: FrozenSet[str] = frozenset()
+    #: per step, per MoE layer, the expert loads of ``route``
+    loads: Optional[List[List[np.ndarray]]] = None
 
 
 def leaf_names(layout) -> List[str]:
@@ -404,10 +472,16 @@ def train_steps(m: Model, opt: Opt, seed: int, batches, *,
     gradients and one block's activations. ``drop_rows`` leaves the last
     rows of each batch out and takes the mean over the rest (a planted
     fault). ``trees`` copies the first gradient and the change to the
-    host."""
+    host. Router state (``Leaf`` init ``state``) takes no gradient and no
+    AdamW step; after each step it becomes ``m.moe.update`` of that step's
+    expert loads."""
     device = device or jax.devices()[0]
     names = leaf_names(m.layout)
+    trained = trained_mask(m.layout)
+    flags = np.array(jax.tree.leaves(trained))
+    state = frozenset(n for n, f in zip(names, flags) if not f)
     kept = {}
+    step_loads = []
     with jax.default_device(device):
         params = init_params(m.layout, seed)
         mom = vel = None
@@ -421,11 +495,12 @@ def train_steps(m: Model, opt: Opt, seed: int, batches, *,
             return jax.tree.map(jnp.add, acc, g), ce
         grad_fn = jax.jit(acc_grad, static_argnums=(6,), donate_argnums=(1,))
         zeros = jax.jit(lambda p: jax.tree.map(jnp.zeros_like, p))
-        sq_norms = jax.jit(lambda g: [jnp.sum(jnp.square(x))
-                                      for x in jax.tree.leaves(g)])
+        sq_norms = jax.jit(lambda g: [
+            jnp.sum(jnp.square(x))
+            for x, f in zip(jax.tree.leaves(g), flags) if f])
         upd = jax.jit(lambda p, g, mm, vv, c, lr, s: jax.tree.map(
-            lambda *a: _adamw(opt, c, lr, s, *a), p, g, mm, vv),
-            donate_argnums=(0, 1, 2, 3))
+            lambda f, *a: _adamw(opt, c, lr, s, *a) if f else a[:1] + a[2:],
+            trained, p, g, mm, vv), donate_argnums=(0, 1, 2, 3))
         losses, first_grads = [], None
         for count, batch in enumerate(batches, start=1):
             tokens = np.asarray(batch["tokens"])
@@ -436,13 +511,15 @@ def train_steps(m: Model, opt: Opt, seed: int, batches, *,
             blocks = [(r, min(r + BLOCK_ROWS, B))
                       for r in range(0, B, BLOCK_ROWS)]
             routes, loads = [], []
-            for _ in range(m.n_moe):
+            for i in range(m.n_moe):
                 logits = np.concatenate([np.asarray(route_fn(
                     params, tokens[a:b], [_rows(r, a, b, S) for r in routes]))
                     for a, b in blocks])
-                sel, keep, load = route(m.moe, logits)
+                sel, keep, load = route(m.moe, logits,
+                                        _router_state(params, i))
                 routes.append((sel, keep))
                 loads.append(load)
+            step_loads.append(loads)
             grads, ce = zeros(params), 0.0
             for a, b in blocks:
                 grads, ce_b = grad_fn(params, grads, tokens[a:b],
@@ -456,17 +533,21 @@ def train_steps(m: Model, opt: Opt, seed: int, batches, *,
             scale = min(1.0, opt.grad_clip / max(gnorm, 1e-9)) \
                 if opt.grad_clip > 0 else 1.0
             if first_grads is None:
-                first_grads = np.sqrt(sq) * scale
+                first_grads = np.zeros(len(names))
+                first_grads[flags] = np.sqrt(sq) * scale
                 if trees:
                     kept["grads"] = {n: np.asarray(x) * np.float32(scale)
                                      for n, x in zip(names, jax.device_get(
-                                         jax.tree.leaves(grads)))}
+                                         jax.tree.leaves(grads)))
+                                     if n not in state}
             # the moments wait on the host while the next gradients are made
             mom = zeros(params) if mom is None else jax.device_put(mom)
             vel = zeros(params) if vel is None else jax.device_put(vel)
             out = upd(params, grads, mom, vel, np.float32(count),
                       np.float32(opt.lr(count)), np.float32(scale))
             params = jax.tree.map(lambda o: o[0], out, is_leaf=_is_triple)
+            if m.moe.update is not None:
+                params = _next_state(m, params, loads)
             mom = vel = None
             if count < len(batches):
                 mom = jax.device_get(jax.tree.map(lambda o: o[1], out,
@@ -484,11 +565,28 @@ def train_steps(m: Model, opt: Opt, seed: int, batches, *,
         if trees:
             kept["change"] = dict(zip(names, jax.device_get(delta)))
         del delta
-    return Steps(losses, first_grads, change, names, **kept)
+    return Steps(losses, first_grads, change, names, **kept,
+                 state_leaves=state, loads=step_loads)
 
 
 def _is_triple(x) -> bool:
     return isinstance(x, tuple) and len(x) == 3 and not isinstance(x, Leaf)
+
+
+def _router_state(params, i: int):
+    """MoE layer ``i``'s router state on the host, or None."""
+    f = params["blocks"]["ffn"]
+    return np.asarray(f[ROUTER_STATE][i]) if ROUTER_STATE in f else None
+
+
+def _next_state(m: Model, params, loads):
+    """``params`` with each MoE layer's router state updated from its
+    loads of the step just taken."""
+    f = params["blocks"]["ffn"]
+    now = np.asarray(f[ROUTER_STATE])
+    new = np.stack([m.moe.update(now[i], loads[i]) for i in range(m.n_moe)])
+    return {**params, "blocks": {**params["blocks"], "ffn": {
+        **f, ROUTER_STATE: jnp.asarray(new, jnp.float32)}}}
 
 
 def _rows(route_, a: int, b: int, S: int):

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from reference.common import (Leaf, Model, causal_attention, rms_norm, rope,
-                              rope_freqs)
+                              rope_freqs, softmax_router)
 
 
 def _attn_layout(hf, n):
@@ -104,8 +104,13 @@ def yarn(hf):
 
 
 def moe_settings(hf) -> dict:
-    return {"num_experts": hf["n_routed_experts"], "top_k": hf["num_experts_per_tok"],
-            "norm_topk": hf["norm_topk_prob"], "aux_coef": hf["aux_loss_alpha"]}
+    """Softmax routing, greedy top-k (``topk_method`` greedy), the chosen
+    probabilities normalised where ``norm_topk_prob``, scale 1
+    (DeepSeek-V2-Lite's ``routed_scaling_factor``); every expert held."""
+    return {"num_experts": hf["n_routed_experts"],
+            "top_k": hf["num_experts_per_tok"],
+            "router": softmax_router(hf["norm_topk_prob"]),
+            "aux_coef": hf["aux_loss_alpha"]}
 
 
 def model(hf, moe) -> Model:

@@ -11,7 +11,8 @@ lists that departure).
 
 from __future__ import annotations
 
-from reference.common import Leaf, Model, causal_attention, rope
+from reference.common import (Leaf, Model, causal_attention, rope,
+                              softmax_router)
 
 
 def layout(hf):
@@ -45,8 +46,12 @@ def layout(hf):
 
 
 def moe_settings(hf) -> dict:
-    return {"num_experts": hf["num_experts"], "top_k": hf["num_experts_per_tok"],
-            "norm_topk": hf["norm_topk_prob"], "aux_coef": hf["router_aux_loss_coef"]}
+    """Softmax routing, greedy top-k, the chosen probabilities normalised
+    where ``norm_topk_prob``, scale 1; every expert held."""
+    return {"num_experts": hf["num_experts"],
+            "top_k": hf["num_experts_per_tok"],
+            "router": softmax_router(hf["norm_topk_prob"]),
+            "aux_coef": hf["router_aux_loss_coef"]}
 
 
 def model(hf, moe) -> Model:

@@ -3,23 +3,28 @@
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell trains through the program's served path: the exactly-once
-``AsyncShuffleEngine`` feeds ``train_shuffle_fed``, whose donated, jitted
-``make_train_step`` runs each step and whose loop reads ``float(loss)``.
-This file wraps the step function to time it and to check what it is
-given, and does not change the loop.
+``AsyncShuffleEngine`` feeds ``ShuffleFedInput``, whose batches go into
+the donated, jitted ``make_train_step``. This file drives that step
+itself (``TimedStep``), as the program's ``train_shuffle_fed`` does, but
+without its ``float(loss)`` after every step: in the window up to
+``ahead_steps`` steps are dispatched beyond the one waited for, and each
+loss is read that many steps late, so that the chip keeps working while
+the host stands still.
 
 Set-up (``setup_s``) runs from process start to the end of the warm-up
 steps: imports, the engine and its submitted stream (as many steps as
 the window could hold were every step at the chip's peak FLOP rate, so
 that no speed-up of the program can end the stream early), parameters made on
 the device from the seed, the step's compile (from the persistent cache
-after a cell's first run) and the warm-up steps. The window then runs
-for ``--seconds`` and ends at the first step boundary after that. Its
-steps give ``tokens_per_s`` (all tokens of the window's steps over the
-window's length) and ``step_p90_s`` (90th percentile of the intervals
-from the end of one step to the end of the next: ``next_batch``, the
-engine's host work, the batch check and the step). Anything compiled
-inside the window fails the run.
+after a cell's first run) and the warm-up steps, each waited for. The
+window then runs for ``--seconds``: once they have passed it sends no
+more steps, waits for all it sent, and closes at the end of the last.
+All of its steps give ``tokens_per_s`` (their tokens over the window's
+length) and ``step_p90_s`` (90th percentile of the intervals between one
+step's end and the next's, as the host sees them: the step's device
+time, and whatever of the host's work, ``next_batch``, the engine and
+the batch check, the queue did not cover). Anything compiled inside the
+window fails the run.
 
 ``correct`` compares, once the window has closed, the state freed and the
 peak memory read:
@@ -27,7 +32,7 @@ peak memory read:
 - every batch the pipeline delivered (warm-up and window) with the stream
   made again from the seed (``stream.py``): exact;
 - the first ``compared_steps`` steps (two; the warm-up runs them through
-  the same loop, feed and compiled step as the window) with as many
+  the same feed and compiled step as the window) with as many
   steps of the plain float32 reference (``reference/``) on the same
   batches: each step's loss (``loss_gap``); per leaf, the first gradient
   as AdamW received it (its first moment after one step over 1 - beta1);
@@ -39,9 +44,15 @@ peak memory read:
   leaf's reference norm and the median leaf's. The reference follows two
   steps and not three so that it takes less time than the window.
 
-With ``--trace 1`` the profiler records ``trace_steps`` steps at the start
-of the window, and the cell's per-layer metrics are read from that trace
-(``trace_reduce.py``, ``metrics/``) in place of the end-to-end ones.
+With ``--trace 1`` the profiler records the window's first
+``trace_steps`` steps, dispatched ahead as the window's and waited for,
+and the cell's per-layer metrics are read from that trace
+(``trace_reduce.py``, ``program_trace.py``, ``metrics/``) in place of the
+end-to-end ones. Such a run also keeps, outside the traced window, what
+the readers need of the program: the traced steps' ``dropped_units``
+counter (read back once the run has ended), the compiled step's HLO text
+(taken after the window, as a map of its operations to the program's
+layers) and the program's own host spans in the profile.
 
 The last line of standard output is the result's JSON; the last lines of
 standard error are the numbers compared, each beside its limit. Without
@@ -57,6 +68,7 @@ import time
 _T0 = time.perf_counter()
 
 import argparse  # noqa: E402
+import collections  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
@@ -66,6 +78,7 @@ import shutil  # noqa: E402
 import statistics  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
+from typing import Optional  # noqa: E402
 
 import numpy as np  # noqa: E402
 
@@ -209,38 +222,49 @@ def make_engine(eng: dict, stream):
 # --- the timed step ----------------------------------------------------------------
 
 class TimedStep:
-    """The step function ``train_shuffle_fed`` calls. Checks the delivered
-    batch, compiles on the first call, reads the first gradient after step
-    one and the parameters' change before step ``compared + 1``, runs the
-    step to its end, and closes the window at the first step boundary
-    after ``seconds``: the loop then stops as after a crash, with no final
-    drain of the engine."""
+    """Drives the cell's step over the pipeline's batches. Checks each
+    delivered batch, compiles on the first step, reads the first gradient
+    after step one and the parameters' change before step ``compared + 1``.
 
-    def __init__(self, step, cell: Cell, seed: int, seconds: float,
-                 trace: bool, watch: CompileWatch, spans: Spans):
+    The warm-up runs one step at a time. The window then keeps up to
+    ``ahead`` steps dispatched beyond the one it waits for, so that the
+    chip has seconds of work queued while the host stands still; a step's
+    loss is read once that many later steps are in flight. When
+    ``seconds`` have passed it sends no more, waits for every step sent,
+    and closes at the end of the last. A traced run traces the window's
+    first ``trace_steps`` steps the same way, waits for them, and goes on
+    untraced until the window closes."""
+
+    def __init__(self, step, pipeline, cell: Cell, seed: int,
+                 seconds: float, trace: bool, watch: CompileWatch,
+                 spans: Spans):
         t = cell.traffic
-        self.step, self.seed, self.seconds = step, seed, seconds
+        self.step, self.pipeline = step, pipeline
+        self.seed, self.seconds = seed, seconds
         self.vocab = cell.config["vocab_size"]
         self.batch, self.seq = t["batch"], t["seq_len"]
         self.warm, self.compared = t["warm_steps"], t["compared_steps"]
+        if self.warm <= self.compared:
+            raise BenchError("warm_steps has to exceed compared_steps")
+        self.ahead = t["ahead_steps"]
         self.beta1 = t["opt"]["beta1"]
         self.trace_steps = t["trace_steps"] if trace else 0
         self.watch, self.spans = watch, spans
         self.compiled = None
         self.compile_s = 0.0
-        self.n = 0
-        self.ends = []
+        self.n = 0                   # steps dispatched
+        self.losses = []             # per step, read in order
+        self.ends = []               # host clock at each step's end
         self.open_at = None          # index in ``ends`` of the window start
-        self.closed = False
         self.checked = self.mismatched = 0
         self.p0 = None
         self.names = None
         self.grads = None            # first gradient, by leaf name, host
         self.change = None           # change over the compared steps
         self.traced = None           # (first, last) step index traced
+        self.dropped = []            # the traced steps' counter, on device
 
     def _check_batch(self, batch) -> None:
-
         want = step_batch(self.seed, self.n, self.vocab, self.batch, self.seq)
         self.checked += 1
         if sorted(batch) != sorted(want) or not all(
@@ -248,22 +272,16 @@ class TimedStep:
                 for k, v in want.items()):
             self.mismatched += 1
 
-    def _window_closed(self) -> bool:
-        return (self.open_at is not None
-                and self.ends[-1] - self.ends[self.open_at] >= self.seconds)
-
-    def __call__(self, params, opt, batch):
+    def _dispatch(self, params, opt):
+        """Fetches the next batch and dispatches its step: the step's
+        (params, opt, metrics), not waited for."""
         import jax
 
-        from repro.train_input.loop import SimulatedCrash
-
+        self.spans.begin("bench.next_batch")
+        got, batch, _ = self.pipeline.next_batch()
         self.spans.end("bench.next_batch")
-        if self._window_closed():
-            self.closed = True
-            self.watch.in_window = False
-            if self.spans.on:          # the window closed before the trace
-                self._stop_trace()
-            raise SimulatedCrash("the measured window has closed")
+        if got != self.n:
+            raise BenchError(f"the pipeline served step {got} for {self.n}")
         self.spans.begin("bench.check_batch")
         self._check_batch(batch)
         self.spans.end("bench.check_batch")
@@ -292,35 +310,59 @@ class TimedStep:
             batch = jax.device_put(batch)
         out = self.compiled(params, opt, batch)
         self.spans.end("bench.dispatch")
-        self.spans.begin("bench.block_loss")
-        jax.block_until_ready(out)
-        self.spans.end("bench.block_loss")
-        self.ends.append(time.perf_counter())
+        if self.spans.on:
+            self.dropped.append(out[2].get("dropped_units"))
         self.n += 1
-        if self.n == self.warm:
-            self.open_at = len(self.ends) - 1
-            self.watch.in_window = True
-            if self.trace_steps:
-                TRACE_DIR.mkdir(exist_ok=True)
-                opts = jax.profiler.ProfileOptions()
-                opts.python_tracer_level = 0   # spans, not every call
-                jax.profiler.start_trace(str(TRACE_DIR),
-                                         profiler_options=opts)
-                self.spans.on = True
-                self.spans.begin("bench.window")
-                self.traced = (self.n, self.n + self.trace_steps - 1)
-        elif self.spans.on and self.n == self.traced[1] + 1:
-            self._stop_trace()
-        self.spans.begin("bench.next_batch")
         return out
 
-    def _stop_trace(self) -> None:
+    def _wait(self, metrics) -> None:
+        """Waits for the step whose ``metrics`` these are (its outputs are
+        ready together) and reads its loss."""
+        self.spans.begin("bench.block_loss")
+        loss = float(metrics["loss"])
+        self.spans.end("bench.block_loss")
+        self.ends.append(time.perf_counter())
+        self.losses.append(loss)
+
+    def _run(self, params, opt, done):
+        """Dispatches steps, keeping ``ahead`` beyond the one waited for,
+        until ``done()``; then waits for all that were sent."""
+        pending = collections.deque()
+        while not done():
+            params, opt, metrics = self._dispatch(params, opt)
+            pending.append(metrics)
+            if len(pending) > self.ahead:
+                self._wait(pending.popleft())
+        while pending:
+            self._wait(pending.popleft())
+        return params, opt
+
+    def run(self, params, opt) -> None:
         import jax
 
-        self.spans.end("bench.window")
-        self.spans.on = False
-        self.traced = (self.traced[0], self.n - 1)
-        jax.profiler.stop_trace()
+        for _ in range(self.warm):
+            params, opt, metrics = self._dispatch(params, opt)
+            self._wait(metrics)
+        self.open_at = len(self.ends) - 1
+        opened = self.ends[-1]
+        self.watch.in_window = True
+        if self.trace_steps:
+            TRACE_DIR.mkdir(exist_ok=True)
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0   # spans, not every call
+            jax.profiler.start_trace(str(TRACE_DIR), profiler_options=opts)
+            self.spans.on = True
+            self.spans.begin("bench.window")
+            last = self.n + self.trace_steps - 1
+            self.traced = (self.n, last)
+            params, opt = self._run(params, opt, lambda: self.n > last)
+            self.spans.end("bench.window")
+            self.spans.on = False
+            jax.profiler.stop_trace()
+        params, opt = self._run(
+            params, opt, lambda: time.perf_counter() - opened >= self.seconds)
+        self.watch.in_window = False
+        jax.block_until_ready((params, opt))
 
 
 # --- the comparison ---------------------------------------------------------------
@@ -354,35 +396,38 @@ def gaps(x, ref, still_leaf_share: float):
     norm and the median leaf's, and the worst leaf is the number. Leaves
     whose reference gradient is under ``still_leaf_share`` of the median
     leaf's are nought to rounding (a key bias under softmax): AdamW moves
-    them by round-off alone, so their change is not compared."""
+    them by round-off alone, so their change is not compared. Router
+    state (``ref.state_leaves``) has no gradient to compare; its change
+    is compared like any other leaf's."""
     if set(x.leaf_names) != set(ref.leaf_names):
         raise BenchError(f"leaves {sorted(x.leaf_names)} differ from the "
                          f"reference's {sorted(ref.leaf_names)}")
     at = {k: i for i, k in enumerate(x.leaf_names)}
     order = [at[k] for k in ref.leaf_names]
-    moving = ref.grad_norms >= still_leaf_share * float(
-        np.median(ref.grad_norms))
+    trained = np.array([k not in ref.state_leaves for k in ref.leaf_names])
+    grad_median = float(np.median(ref.grad_norms[trained]))
+    moving = ~trained | (ref.grad_norms >= still_leaf_share * grad_median)
 
-    def diff(a, b):
-        return np.array([float(np.linalg.norm(a[k] - b[k]))
-                         for k in ref.leaf_names])
+    def diff(a, b, include):
+        return np.array([float(np.linalg.norm(a[k] - b[k])) if i else 0.0
+                         for k, i in zip(ref.leaf_names, include)])
     per_leaf = {
         "grad_gap": (np.abs(x.grad_norms[order] - ref.grad_norms),
-                     ref.grad_norms, None),
+                     ref.grad_norms, grad_median, trained),
         "change_gap": (np.abs(x.change_norms[order] - ref.change_norms),
-                       ref.change_norms, moving),
-        "grad_diff": (diff(x.grads, ref.grads), ref.grad_norms, None),
-        "change_diff": (diff(x.change, ref.change), ref.change_norms,
-                        moving),
+                       ref.change_norms, None, moving),
+        "grad_diff": (diff(x.grads, ref.grads, trained), ref.grad_norms,
+                      grad_median, trained),
+        "change_diff": (diff(x.change, ref.change, moving),
+                        ref.change_norms, None, moving),
     }
     values = {"loss_gap": float(max(
         abs(a - b) for a, b in zip(x.losses, ref.losses)))}
     worst = {}
-    for key, (gap, norm, include) in per_leaf.items():
-        rel = gap / np.maximum(np.maximum(norm, float(np.median(norm))),
-                               1e-30)
-        if include is not None:
-            rel = np.where(include, rel, 0.0)
+    for key, (gap, norm, median, include) in per_leaf.items():
+        median = float(np.median(norm)) if median is None else median
+        rel = np.where(include, gap / np.maximum(np.maximum(norm, median),
+                                                 1e-30), 0.0)
         j = int(np.argmax(rel))
         values[key] = float(rel[j])
         worst[key] = ref.leaf_names[j]
@@ -456,14 +501,27 @@ def reference_steps(cell: Cell, seed: int, groups: int, device, *,
 
 
 def stream_steps(cell: Cell, seconds: float, peaks, chips: int) -> int:
-    """Steps of the stream the engine is given: the warm-up, and as many
-    as the window could hold were each step to run at the chips' peak
-    bf16 rate (model FLOPs cannot pass it), and one to close the window."""
+    """Steps of the stream the engine is given: the warm-up, as many as
+    the window could hold were each step to run at the chips' peak bf16
+    rate (model FLOPs cannot pass it), and the ``ahead_steps`` and one
+    more that may be sent before the window's last step ends."""
     t = cell.traffic
     flops = cell.module("flops").step_flops(cell.config, t["batch"],
                                             t["seq_len"])
     fastest_s = flops / (chips * peaks["bf16_flops"])
-    return t["warm_steps"] + math.ceil(seconds / fastest_s) + 1
+    return (t["warm_steps"] + math.ceil(seconds / fastest_s)
+            + t["ahead_steps"] + 1)
+
+
+def routed_units(cell: Cell, model_cfg) -> Optional[int]:
+    """Routed units of one step: tokens x experts per token x MoE layers;
+    None for a model with no MoE layer."""
+    moe = model_cfg.moe
+    if moe is None:
+        return None
+    t = cell.traffic
+    return (t["batch"] * t["seq_len"] * moe.top_k
+            * (model_cfg.num_layers - moe.first_dense_layers))
 
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
@@ -474,8 +532,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
 
 def run_once(cell: Cell, seed: int, seconds: float, trace: bool, *,
-             peaks=None):
-    """(result line, the reference's steps with their trees)."""
+             peaks=None, keep: Optional[dict] = None):
+    """(result line, the reference's steps with their trees). A traced
+    run given ``keep`` leaves there the raw profile (``profile``, bytes)
+    and the readers' context (``ctx``)."""
     import jax
 
     devices = check_devices(cell.chips, require_tpu=peaks is None)
@@ -495,26 +555,30 @@ def run_once(cell: Cell, seed: int, seconds: float, trace: bool, *,
                                   batch=t["batch"], seq_len=t["seq_len"],
                                   seed=seed)
     n_steps = stream_steps(cell, seconds, peaks, len(used))
+    pipeline = ti.ShuffleFedInput(make_engine(t["engine"], stream), stream,
+                                  steps=n_steps, mesh=mesh,
+                                  model_cfg=model_cfg, **t["pipeline"])
+    pipeline.submit()
+    params, opt = ti.init_train_state(model_cfg, seed, mesh)
     step = TimedStep(
         jax.jit(training.make_train_step(model_cfg, tcfg, mesh=mesh),
                 donate_argnums=(0, 1)),
-        cell, seed, seconds, trace, watch, spans)
-    res = ti.train_shuffle_fed(
-        model_cfg, tcfg, mesh, stream, steps=n_steps,
-        engine_factory=lambda: make_engine(t["engine"], stream),
-        step_fn=step, init_seed=seed, pipeline_kwargs=t["pipeline"])
-    spans.end("bench.next_batch")
-    if not step.closed:
+        pipeline, cell, seed, seconds, trace, watch, spans)
+    try:
+        step.run(params, opt)
+    except StopIteration as e:
         raise BenchError(f"the stream of {n_steps} steps ended before the "
-                         f"{seconds} s window closed")
+                         f"{seconds} s window closed") from e
+    del params, opt, pipeline
+    step.pipeline = None
     if watch.window_events:
         raise BenchError(f"compiled inside the window: "
                          f"{watch.window_events}")
     watch.close()
     memory_peak = max(int((d.memory_stats() or {}).get(
         "peak_bytes_in_use", 0)) for d in used)
-    losses = list(res.losses)
-    del res
+    losses = step.losses
+    hlo = step.compiled.as_text() if trace else None
     step.compiled = step.step = None
     gc.collect()
 
@@ -539,7 +603,8 @@ def run_once(cell: Cell, seed: int, seconds: float, trace: bool, *,
               "memory_peak_bytes": memory_peak}
     units = {m["name"]: m["unit"] for m in cell.end_to_end + cell.per_layer}
     if trace:
-        metrics, extra = read_trace(cell, step, peaks, len(used))
+        metrics, extra = read_trace(cell, step, peaks, len(used), hlo,
+                                    routed_units(cell, model_cfg), keep)
         device.update(extra["device"])
         result["breakdown"] = extra["breakdown"]
     else:
@@ -569,12 +634,33 @@ def run_once(cell: Cell, seed: int, seconds: float, trace: bool, *,
     return result, ref
 
 
-def read_trace(cell: Cell, step: TimedStep, peaks, chips: int):
-    """Per-layer metrics of the cell from the traced steps."""
+def read_trace(cell: Cell, step: TimedStep, peaks, chips: int, hlo: str,
+               routed: Optional[int], keep: Optional[dict] = None):
+    """Per-layer metrics of the cell from the traced steps. The readers'
+    ``ctx`` holds the reduced trace (``trace``), the traced ``steps``,
+    ``chips``, ``peaks``, ``step_flops``, and of the program: ``layer_of``
+    ({operation: layer} of the compiled step's ``hlo``, for the operations
+    that ran), ``program_spans`` (its host spans in the profile),
+    ``dropped`` (the traced steps' counter, or None where the step has
+    none) and ``routed_units`` (per step, or None), and ``program``, the
+    numbers ``program_trace.metrics`` works out from those, once."""
+    import jax
+
+    import program_trace
     import trace_reduce
 
-    red = trace_reduce.reduce_dir(TRACE_DIR)
+    path = trace_reduce.find_profile(TRACE_DIR)
+    profile = jax.profiler.ProfileData.from_file(path)
+    if keep is not None:
+        keep["profile"] = pathlib.Path(path).read_bytes()
     shutil.rmtree(TRACE_DIR, ignore_errors=True)
+    red = trace_reduce.reduce_profile(profile)
+    ran = {program_trace.instruction(n) for d in red.devices
+           for n, _, _ in red.ops[d]}
+    dropped = None
+    if step.dropped and all(v is not None for v in step.dropped):
+        dropped = [int(v) for v in jax.device_get(step.dropped)]
+    step.dropped = []
     t = cell.traffic
     first, last = step.traced
     ctx = {
@@ -582,7 +668,16 @@ def read_trace(cell: Cell, step: TimedStep, peaks, chips: int):
         "peaks": peaks,
         "step_flops": cell.module("flops").step_flops(
             cell.config, t["batch"], t["seq_len"]),
+        "layer_of": {k: v for k, v in program_trace.layer_of_ops(
+            hlo).items() if k in ran},
+        "program_spans": program_trace.program_spans(profile),
+        "dropped": dropped, "routed_units": routed,
     }
+    ctx["program"] = program_trace.metrics(
+        red, ctx["program_spans"], ctx["layer_of"], ctx["steps"], dropped,
+        routed)
+    if keep is not None:
+        keep["ctx"] = ctx
     metrics = {}
     for m in cell.per_layer:
         value = metric_reader(m["name"])(ctx)

@@ -3,7 +3,8 @@
 partition the busy time and the idle split sums to the gaps, and on a
 trace recorded on a TPU v5 lite chip by ``record_trace.py``
 (``data/program_trace.*``: eight steps of the deepseek cell with the
-program's scopes and spans, the step's layer map and dropped units)."""
+program's scopes and spans, the step's layer map and dropped units); and
+the readers of ``metrics/`` over what ``run.read_trace`` keeps of it."""
 
 import gzip
 import json
@@ -143,3 +144,115 @@ def test_recorded_trace_input_and_idle(recorded):
         "input.assemble_ms": 0.202664, "device.idle_input_ms": 0.815793625,
         "device.idle_wait_ms": 2.840629375,
         "moe.dropped_share": 53.576151529947914}, rel=1e-9)
+
+
+# --- the readers of metrics/ over what a traced run keeps ----------------------
+
+#: the per-layer metrics read from the program's layers, spans and counter
+READERS = ["step.attention_ms", "step.moe_dispatch_ms", "step.moe_experts_ms",
+           "step.ffn_ms", "step.head_ms", "step.optimizer_ms",
+           "step.other_ms", "device.idle_input_ms", "device.idle_wait_ms",
+           "moe.dropped_share"]
+@pytest.fixture(scope="module")
+def traced(recorded, tmp_path_factory):
+    """``run.read_trace`` over the recorded profile, counter and layer map
+    (in place of the HLO text, which the recording does not keep): its
+    metrics and the readers' ``ctx``."""
+    import types
+
+    import numpy as np
+
+    import program_trace
+    import run
+    from cell import load_cell, peaks_of
+
+    _, _, layer_of, counter = recorded
+    trace_dir = tmp_path_factory.mktemp("trace")
+    (trace_dir / "run.xplane.pb").write_bytes(
+        gzip.decompress((DATA / "program_trace.xplane.pb.gz").read_bytes()))
+    cell = load_cell("deepseek-v2-lite-2l.shuffle-fed")
+    step = types.SimpleNamespace(
+        traced=(5, 12), dropped=[np.int32(v) for v in counter["dropped_units"]])
+    keep = {}
+    plain_dir, plain_map = run.TRACE_DIR, program_trace.layer_of_ops
+    run.TRACE_DIR = trace_dir
+    program_trace.layer_of_ops = lambda hlo: layer_of
+    try:
+        got, extra = run.read_trace(cell, step, peaks_of("TPU v5 lite"), 1,
+                                    "", counter["routed_units_per_step"],
+                                    keep)
+    finally:
+        run.TRACE_DIR, program_trace.layer_of_ops = plain_dir, plain_map
+    assert not trace_dir.exists()
+    return got, keep["ctx"], extra
+
+
+def test_readers_give_what_metrics_gives(recorded, traced):
+    red, spans, layer_of, counter = recorded
+    want = metrics(red, spans, layer_of, 8, counter["dropped_units"],
+                   counter["routed_units_per_step"])
+    got, ctx, _ = traced
+    assert ctx["dropped"] == counter["dropped_units"]
+    assert set(READERS) <= set(got)
+    assert {k: got[k] for k in READERS} == {k: want[k] for k in READERS}
+    layers = sum(got[f"step.{k}_ms"] for k in LAYERS)
+    assert layers == pytest.approx(got["step.device_ms"], rel=5e-3)
+
+
+@pytest.mark.parametrize("name", READERS)
+def test_reader_gives_none_without_its_key(traced, name):
+    from cell import metric_reader
+
+    _, ctx, _ = traced
+    read = metric_reader(name)
+    assert read(ctx) == ctx["program"][name]
+    assert read({k: v for k, v in ctx.items() if k != "program"}) is None
+    lacking = {k: v for k, v in ctx["program"].items() if k != name}
+    assert read(dict(ctx, program=lacking)) is None
+
+
+def test_no_dropped_share_without_counter_or_routed_units(recorded):
+    red, spans, layer_of, counter = recorded
+    for dropped, routed in ((None, counter["routed_units_per_step"]),
+                            (counter["dropped_units"], None)):
+        got = metrics(red, spans, layer_of, 8, dropped, routed)
+        assert "moe.dropped_share" not in got
+        assert set(READERS) - set(got) == {"moe.dropped_share"}
+
+
+def test_every_per_layer_metric_has_a_reader():
+    from cell import BENCH, load_cell, metric_reader
+
+    bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
+    names = [m["name"] for m in bench["per_layer"]]
+    assert set(READERS) <= set(names)
+    for name in names:
+        assert (BENCH / "metrics" / f"{name}.py").is_file(), name
+        assert callable(metric_reader(name))
+    # the program's layers are read in every cell, present and to come
+    for w in bench["workloads"]:
+        per_layer = {m["name"] for m in load_cell(w["name"]).per_layer}
+        assert set(READERS) <= per_layer, w["name"]
+
+
+def test_layer_map_finds_the_programs_scopes(deepseek_tiny):
+    """The yardstick's layer map (``program_trace.layer_of_ops``) knows the
+    program's scope names and, on a compiled train step of the tiny
+    deepseek type, finds each of them and ``other``."""
+    import jax
+
+    import program_trace
+    import run
+    from repro.models import init_params, lm
+    from repro.obs.scopes import LAYERS as SCOPE_NAMES
+    from repro.training import adamw_init, make_train_step
+    from stream import step_batch
+
+    assert set(program_trace.SCOPES) == set(SCOPE_NAMES)
+    model_cfg, tcfg, _, _ = run.program_config(deepseek_tiny)
+    params = init_params(lm.param_defs(model_cfg), jax.random.key(1))
+    t = deepseek_tiny.traffic
+    batch = step_batch(1, 0, model_cfg.vocab_size, t["batch"], t["seq_len"])
+    hlo = jax.jit(make_train_step(model_cfg, tcfg)).lower(
+        params, adamw_init(params), batch).compile().as_text()
+    assert set(program_trace.layer_of_ops(hlo).values()) == set(LAYERS)

@@ -179,12 +179,6 @@ def reduce_profile(profile) -> Reduced:
                    {d: clip(async_ops.get(d, [])) for d in ops})
 
 
-def reduce_file(path: str) -> Reduced:
-    import jax
-
-    return reduce_profile(jax.profiler.ProfileData.from_file(path))
-
-
 def find_profile(directory) -> str:
     found = glob.glob(os.path.join(str(directory), "**", "*.xplane.pb"),
                       recursive=True)
@@ -192,7 +186,3 @@ def find_profile(directory) -> str:
         raise ValueError(f"expected one .xplane.pb under {directory}, "
                          f"found {found}")
     return found[0]
-
-
-def reduce_dir(directory) -> Reduced:
-    return reduce_file(find_profile(directory))
